@@ -64,7 +64,9 @@ def squared_l2_distances(queries: jax.Array, points: jax.Array) -> jax.Array:
     """
     q2 = jnp.sum(queries.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
     p2 = jnp.sum(points.astype(jnp.float32) ** 2, axis=-1)
-    qp = queries.astype(jnp.float32) @ points.astype(jnp.float32).T
+    qp = jnp.matmul(queries.astype(jnp.float32),
+                    points.astype(jnp.float32).T,
+                    precision=lax.Precision.HIGHEST)   # not a bf16 pass
     return jnp.maximum(q2 - 2.0 * qp + p2[None, :], 0.0)
 
 

@@ -302,9 +302,8 @@ def select_l_smallest(
     # The loop body mixes the (replicated) control state with per-shard data,
     # so under shard_map's varying-manual-axes checking the carry must be
     # marked as varying over the machine axis up front.
-    if hasattr(lax, "pcast"):
-        state = jax.tree.map(
-            lambda x: lax.pcast(x, (axis_name,), to="varying"), state)
+    state = jax.tree.map(
+        lambda x: lax.pcast(x, (axis_name,), to="varying"), state)
 
     body = partial(_select_body, v=v, i=i, valid=valid, axis_name=axis_name,
                    num_pivots=num_pivots)

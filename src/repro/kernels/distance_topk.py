@@ -108,7 +108,8 @@ def _kernel(q_ref, p_ref, *refs, nj: int, nk: int, l: int,
     q = q_ref[...].astype(jnp.float32)
     p = p_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
-        q, p, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q, p, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     q2_ref[...] += jnp.sum(q * q, axis=1, keepdims=True)
     p2_ref[...] += jnp.sum(p * p, axis=1)[None, :]
 

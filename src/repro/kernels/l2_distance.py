@@ -50,9 +50,12 @@ def _kernel(q_ref, p_ref, out_ref, acc_ref, q2_ref, p2_ref, *, nk: int):
     q = q_ref[...].astype(jnp.float32)
     p = p_ref[...].astype(jnp.float32)
 
-    # MXU contraction: (bb, bk) x (bk, bm).
+    # MXU contraction: (bb, bk) x (bk, bm), at full f32 precision (the
+    # default runs as one bf16 pass on TPU, far outside the f32 rounding
+    # bound exact search is held to).
     acc_ref[...] += jax.lax.dot_general(
         q, p, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     # Norm partials on the VPU, same operands, no extra HBM traffic.
     q2_ref[...] += jnp.sum(q * q, axis=1, keepdims=True)

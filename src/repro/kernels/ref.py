@@ -18,13 +18,15 @@ def l2_distance_ref(queries: jax.Array, points: jax.Array) -> jax.Array:
 
     Matches the kernel's contraction order: d = |q|^2 - 2 q.p + |p|^2,
     clamped at zero (the expansion can go epsilon-negative in finite
-    precision; distances are non-negative by definition).
+    precision; distances are non-negative by definition).  The dot runs
+    at full f32 precision, like the kernels (TPU's default is one bf16
+    pass).
     """
     q = queries.astype(jnp.float32)
     p = points.astype(jnp.float32)
     q2 = jnp.sum(q * q, axis=-1, keepdims=True)
     p2 = jnp.sum(p * p, axis=-1)
-    qp = q @ p.T
+    qp = jnp.matmul(q, p.T, precision=lax.Precision.HIGHEST)
     return jnp.maximum(q2 - 2.0 * qp + p2[None, :], 0.0)
 
 

@@ -160,6 +160,7 @@ def _route_rows(q, l_arr, centsT, radii, live, loT, hiT, pivT, pivrT,
 
     # projection-sketch lower bound (1-Lipschitz interval gaps)
     qp = jax.lax.dot_general(q, dirsT, (((1,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     for rr in range(r):
         gap = jnp.maximum(jnp.maximum(

@@ -97,8 +97,7 @@ def divisible(pspec: P, shape, mesh) -> P:
     layer counts) the offending axes are dropped: the tensor arrives
     replicated on those axes and the first internal constraint reshards it.
     """
-    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes)) if hasattr(
-        mesh, "axis_sizes") else {k: v for k, v in mesh.shape.items()}
+    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
     out = []
     entries = list(pspec) + [None] * (len(shape) - len(pspec))
     for dim, entry in zip(shape, entries):

@@ -28,8 +28,5 @@ def replicate(x: jax.Array, axis_name) -> jax.Array:
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis (works across jax generations)."""
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis_name))
-    from jax.core import trace_ctx
-    return int(trace_ctx.axis_env.axis_size(axis_name))
+    """Static size of a bound mesh axis."""
+    return int(lax.axis_size(axis_name))

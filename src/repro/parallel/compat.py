@@ -1,17 +1,10 @@
-"""jax version-compatibility shims for the mesh/shard_map API surface.
+"""One import site for the mesh / shard_map API surface.
 
-The framework is written against the modern jax API (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``).  Older jax
-releases (< 0.5) expose the same functionality under different names and
-signatures (``jax.experimental.shard_map.shard_map`` with ``check_rep``,
-``make_mesh`` without ``axis_types``, ``Mesh`` as a plain context manager).
-Every mesh/shard_map call site in the repo routes through this module so the
-whole stack — core algorithms, serving runtime, benchmarks, tests — runs on
-either generation with no behavioral difference.
-
-Only signature/name differences are papered over here; semantics shims
-belong next to the code that needs them (e.g. the ``lax.pcast`` guard in
-core/selection.py).
+Thin aliases over the installed jax (>= 0.9): ``jax.shard_map``,
+``jax.make_mesh`` with explicit ``Auto`` axis types, ``jax.set_mesh`` and
+the abstract ambient mesh.  Call sites across the core algorithms, the
+serving runtime, benchmarks and tests import these names from here, so a
+future API rename is absorbed in one file.
 """
 
 from __future__ import annotations
@@ -19,85 +12,38 @@ from __future__ import annotations
 import contextlib
 
 import jax
-
-# Modern jax promotes shard_map out of experimental; use its presence as the
-# API-generation probe for the whole surface.
-IS_MODERN = hasattr(jax, "shard_map")
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` on modern jax, experimental shard_map on old.
-
-    ``check_vma`` toggles the "outputs claimed replicated must be provably
-    replicated" verifier.  The old-generation equivalent (``check_rep``) has
-    no replication rule for ``while_loop`` — which Algorithm 1 is built on —
-    so on old jax the verifier is always off; it remains a modern-jax-only
-    safety net.
-    """
-    if IS_MODERN:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map``; ``check_vma`` toggles the "outputs claimed
+    replicated must be provably replicated" verifier."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """Mesh of host devices with fully-automatic axis types everywhere."""
-    if IS_MODERN:
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(tuple(axis_names)))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """Mesh of the process's devices with ``Auto`` axis types everywhere."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(tuple(axis_names)))
 
 
 def ambient_mesh_axis_names():
-    """Axis names of the mesh installed by :func:`set_mesh`, or None.
-
-    Modern jax exposes the ambient mesh abstractly
-    (``jax.sharding.get_abstract_mesh``); old jax keeps the physical mesh in
-    a thread-local resource env.
-    """
-    mesh = _ambient_mesh()
-    return set(mesh.axis_names) if mesh is not None else None
+    """Axis names of the mesh installed by :func:`set_mesh`, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else set(mesh.axis_names)
 
 
 def ambient_mesh_axis_sizes():
     """{axis name: size} of the ambient mesh, or None if no mesh is set."""
-    mesh = _ambient_mesh()
-    if mesh is None:
-        return None
-    if hasattr(mesh, "axis_sizes"):
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    return dict(mesh.shape)
-
-
-def _ambient_mesh():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except AttributeError:
-        try:
-            from jax._src.mesh import thread_resources
-            mesh = thread_resources.env.physical_mesh
-            if mesh is not None and not mesh.empty:
-                return mesh
-        except (ImportError, AttributeError):
-            pass
-    return None
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else dict(zip(mesh.axis_names,
+                                            mesh.axis_sizes))
 
 
 def set_mesh(mesh):
-    """Context manager binding ``mesh`` as the ambient mesh.
-
-    Modern jax: ``jax.set_mesh``.  Old jax: ``Mesh`` is itself a context
-    manager that installs the axis environment; ``None`` means "no mesh".
-    """
+    """Context manager binding ``mesh`` as the ambient mesh; ``None``
+    means "no mesh"."""
     if mesh is None:
         return contextlib.nullcontext()
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return jax.set_mesh(mesh)

@@ -559,105 +559,11 @@ class KnnServer:
 
     # ---- compiled dispatch ---------------------------------------------
 
-    def _distances_fn(self):
-        if self.cfg.distance_impl == "auto":
-            # masked-aware: pushes a store's valid mask down into the
-            # kernels layer (core/knn._masked_distances convention)
-            def fn(q, p, valid=None):
-                return kops.l2_distance(q, p, valid=valid)
-            fn.supports_valid = True
-            return fn
-        # plain jnp path: _masked_distances applies the mask when needed
-        return knn_mod.squared_l2_distances
-
     def _build_executable(self):
-        cfg = self.cfg
-        axis = self.axis_name
-        l_max = cfg.l_max
-        distances_fn = self._distances_fn()
-        # The valid-mask operand exists only for store-backed servers;
-        # static servers keep the unmasked executable (no per-query
-        # masking cost for a point set that can never change).
-        masked = self._store is not None
-
-        # route="pruned" adds one (k,) bool operand; in_spec P(axis) hands
-        # each shard its own flag, which core/knn folds into the valid
-        # mask ahead of the fused distance+top-l kernel.
-        routed = cfg.route == "pruned"
-        # search="approx" adds one (n,) bool per-slot candidate operand —
-        # the bucket index's keep decision, folded into the same mask
-        # (core/knn point_candidates); P(axis) hands each shard its own
-        # slots.
-        indexed = self._indexed
-        # cfg.predict adds one (n,) f32 per-slot label operand carried
-        # through the local top-l permutation (core/knn local_top_l
-        # extra=), and two replicated outputs: the predicted label and
-        # its confidence, folded from the winner mask inside the same
-        # program (predict/vote.py — one extra psum).
-        predicting = self._predict
-
-        if cfg.sampler == "selection":
-            def body(pts, pids, pvalid, plabels, pcand, active, q, l_arr,
-                     key):
-                res = knn_mod.knn_query_batched(
-                    pts, pids, q, l_max, l_arr, key, axis_name=axis,
-                    distances_fn=distances_fn,
-                    use_sampling=cfg.use_sampling,
-                    num_pivots=cfg.num_pivots,
-                    point_valid=pvalid, shard_active=active,
-                    point_candidates=pcand, point_labels=plabels)
-                out = (res.dists, res.ids, res.selection.iterations,
-                       res.prune.survivors)
-                if plabels is None:
-                    return out
-                label, conf, _detail = predict_mod.exact_predict(
-                    res, l_arr, predict=cfg.predict,
-                    num_classes=cfg.num_classes, axis_name=axis)
-                return out + (label, conf)
-        elif cfg.sampler == "gather":
-            def body(pts, pids, pvalid, plabels, pcand, active, q, l_arr,
-                     key):
-                sd, si = knn_mod.knn_simple(
-                    pts, pids, q, l_max, axis_name=axis,
-                    distances_fn=distances_fn, point_valid=pvalid,
-                    shard_active=active, point_candidates=pcand)
-                # per-request l: slots at rank >= l[b] are masked to the
-                # sentinel (knn_simple returns ascending order).
-                keep = jnp.arange(l_max)[None, :] < l_arr[:, None]
-                sd = jnp.where(keep, sd, jnp.inf)
-                si = jnp.where(keep, si, _ID_SENTINEL)
-                zeros = jnp.zeros(q.shape[:1], jnp.int32)
-                return sd, si, jnp.int32(0), zeros
-        else:
-            raise ValueError(f"unknown sampler {cfg.sampler!r}")
-
-        # Operand layout composes by flag, always in this order:
-        #   pts, pids, [pvalid], [plabels], [pcand], [active], q, l_arr, key
-        # — every present optional operand is sharded P(axis).  The
-        # dispatch/warmup/replay sites assemble operands in the same
-        # order from the same flags.
-        def fn(*a):
-            it = iter(a)
-            pts, pids = next(it), next(it)
-            pvalid = next(it) if masked else None
-            plabels = next(it) if predicting else None
-            pcand = next(it) if indexed else None
-            active = next(it) if routed else None
-            q, l_arr, key = next(it), next(it), next(it)
-            return body(pts, pids, pvalid, plabels, pcand, active, q,
-                        l_arr, key)
-
-        n_sharded = (2 + int(masked) + int(predicting) + int(indexed)
-                     + int(routed))
-        in_specs = (P(axis),) * n_sharded + (P(None), P(None), P(None))
-        out_specs = (P(None), P(None), P(), P(None))
-        if predicting:
-            out_specs = out_specs + (P(None), P(None))
-
-        return jax.jit(shard_map(
-            fn, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False))
+        return build_query_program(
+            self.cfg, self.mesh, self.axis_name,
+            masked=self._store is not None, predicting=self._predict,
+            indexed=self._indexed)
 
     def _build_ensemble_executable(self):
         """The one-message-per-shard prediction program (predict/
@@ -678,7 +584,7 @@ class KnnServer:
         cfg = self.cfg
         axis = self.axis_name
         l_max = cfg.l_max
-        distances_fn = self._distances_fn()
+        distances_fn = _distances_fn(cfg)
         masked = self._store is not None
         routed = cfg.route == "pruned"
         vote = cfg.predict == "vote"
@@ -1562,6 +1468,105 @@ class KnnServer:
                 chunk = self._take_chunk_locked()
             if chunk:
                 self._dispatch(chunk)
+
+
+def _distances_fn(cfg: KnnServiceConfig):
+    if cfg.distance_impl == "auto":
+        # masked-aware: pushes a store's valid mask down into the
+        # kernels layer (core/knn._masked_distances convention)
+        def fn(q, p, valid=None):
+            return kops.l2_distance(q, p, valid=valid)
+        fn.supports_valid = True
+        return fn
+    # plain jnp path: _masked_distances applies the mask when needed
+    return knn_mod.squared_l2_distances
+
+
+def build_query_program(cfg: KnnServiceConfig, mesh, axis_name: str, *,
+                        masked: bool, predicting: bool, indexed: bool):
+    """The service's jitted query program for one backing layout.
+
+    ``masked`` adds the store's (n,) valid-mask operand (store-backed
+    servers; static servers keep the unmasked program — no per-query
+    masking cost for a point set that can never change).  The mesh only
+    fixes the shard_map, so the program can be lowered against a
+    described device topology without constructing a server.
+    """
+    axis = axis_name
+    l_max = cfg.l_max
+    distances_fn = _distances_fn(cfg)
+    # route="pruned" adds one (k,) bool operand; in_spec P(axis) hands
+    # each shard its own flag, which core/knn folds into the valid mask
+    # ahead of the fused distance+top-l kernel.
+    routed = cfg.route == "pruned"
+    # search="approx" (``indexed``) adds one (n,) bool per-slot candidate
+    # operand — the bucket index's keep decision, folded into the same
+    # mask (core/knn point_candidates); P(axis) hands each shard its own
+    # slots.  cfg.predict (``predicting``) adds one (n,) f32 per-slot
+    # label operand carried through the local top-l permutation (core/knn
+    # local_top_l extra=), and two replicated outputs: the predicted
+    # label and its confidence, folded from the winner mask inside the
+    # same program (predict/vote.py — one extra psum).
+
+    if cfg.sampler == "selection":
+        def body(pts, pids, pvalid, plabels, pcand, active, q, l_arr, key):
+            res = knn_mod.knn_query_batched(
+                pts, pids, q, l_max, l_arr, key, axis_name=axis,
+                distances_fn=distances_fn,
+                use_sampling=cfg.use_sampling,
+                num_pivots=cfg.num_pivots,
+                point_valid=pvalid, shard_active=active,
+                point_candidates=pcand, point_labels=plabels)
+            out = (res.dists, res.ids, res.selection.iterations,
+                   res.prune.survivors)
+            if plabels is None:
+                return out
+            label, conf, _detail = predict_mod.exact_predict(
+                res, l_arr, predict=cfg.predict,
+                num_classes=cfg.num_classes, axis_name=axis)
+            return out + (label, conf)
+    elif cfg.sampler == "gather":
+        def body(pts, pids, pvalid, plabels, pcand, active, q, l_arr, key):
+            sd, si = knn_mod.knn_simple(
+                pts, pids, q, l_max, axis_name=axis,
+                distances_fn=distances_fn, point_valid=pvalid,
+                shard_active=active, point_candidates=pcand)
+            # per-request l: slots at rank >= l[b] are masked to the
+            # sentinel (knn_simple returns ascending order).
+            keep = jnp.arange(l_max)[None, :] < l_arr[:, None]
+            sd = jnp.where(keep, sd, jnp.inf)
+            si = jnp.where(keep, si, _ID_SENTINEL)
+            zeros = jnp.zeros(q.shape[:1], jnp.int32)
+            return sd, si, jnp.int32(0), zeros
+    else:
+        raise ValueError(f"unknown sampler {cfg.sampler!r}")
+
+    # Operand layout composes by flag, always in this order:
+    #   pts, pids, [pvalid], [plabels], [pcand], [active], q, l_arr, key
+    # — every present optional operand is sharded P(axis).  The
+    # dispatch/warmup/replay sites assemble operands in the same order
+    # from the same flags.
+    def fn(*a):
+        it = iter(a)
+        pts, pids = next(it), next(it)
+        pvalid = next(it) if masked else None
+        plabels = next(it) if predicting else None
+        pcand = next(it) if indexed else None
+        active = next(it) if routed else None
+        q, l_arr, key = next(it), next(it), next(it)
+        return body(pts, pids, pvalid, plabels, pcand, active, q, l_arr,
+                    key)
+
+    n_sharded = (2 + int(masked) + int(predicting) + int(indexed)
+                 + int(routed))
+    in_specs = (P(axis),) * n_sharded + (P(None), P(None), P(None))
+    out_specs = (P(None), P(None), P(), P(None))
+    if predicting:
+        out_specs = out_specs + (P(None), P(None))
+
+    return jax.jit(shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False))
 
 
 def _resolve(future: Future, result=None, error=None):

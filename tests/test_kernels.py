@@ -169,3 +169,26 @@ def test_masked_l2_distance(rng):
     want = np.asarray(ref.masked_l2_distance_ref(q, p, valid))
     np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-3)
     assert np.all(np.isinf(out[:, ~valid]))
+
+
+@pytest.mark.parametrize("path", ["l2_kernel", "dtk_kernel", "ref",
+                                  "jnp"])
+def test_distance_matmul_runs_at_full_f32_precision(path):
+    """Every distance matmul of exact search asks for HIGHEST: on a TPU
+    the default precision is one bf16 pass, whose error is ~100x the f32
+    rounding bound the answers are checked against (chip_smoke.py)."""
+    import jax
+    from repro.core.knn import squared_l2_distances
+    q, p = jnp.zeros((8, 128)), jnp.zeros((256, 128))
+    fn = {
+        "l2_kernel": lambda: l2_kernel(jnp.zeros((128, 128)), p,
+                                       block_k=128, interpret=True),
+        "dtk_kernel": lambda: dtk_kernel(q, p, 8, block_k=128,
+                                         interpret=True),
+        "ref": lambda: ref.l2_distance_ref(q, p),
+        "jnp": lambda: squared_l2_distances(q, p),
+    }[path]
+    jaxpr = str(jax.make_jaxpr(fn)())
+    assert "dot_general" in jaxpr
+    assert jaxpr.count("precision=(Precision.HIGHEST") == jaxpr.count(
+        "dot_general")
