@@ -40,11 +40,16 @@ _LOG_G = math.log(GROWTH)
 _SQRT_G = GROWTH ** 0.5
 
 
+# Counter and Histogram locks are re-entrant: the collector hook
+# (obs/gcwatch.py) counts from inside a collection, and a collection can
+# start on a thread that is holding the very lock, between two bytecodes.
+
+
 class Counter:
     __slots__ = ("_lock", "value")
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self.value = 0
 
     def inc(self, n: int = 1) -> None:
@@ -77,7 +82,7 @@ class Histogram:
                  "_underflow")
 
     def __init__(self):
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self.count = 0
         self.total = 0.0
         self.min = math.inf

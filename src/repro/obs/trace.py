@@ -38,6 +38,22 @@ object per line, for offline assembly into trees.
 ``NULL_TRACER`` is the disabled plane: every call funnels to a shared
 no-op span, no lock, no allocation — the `obs=off` arm the ≤10%
 overhead guard (tests/test_obs.py) compares against.
+
+Profiler sink
+-------------
+The ring's clock is not the device's, so a ring span cannot say what
+the host was doing while the device sat idle.  A layer that imports
+jax installs ``jax.profiler.TraceAnnotation`` with
+:func:`set_profiler_sink`; from then on, while a profiler session
+records (``is_enabled()``), every span begun with ``same_thread=True``
+(the default) is also an annotation named ``knn.<span name>`` on its
+thread's line of the profiler's host plane, from ``begin`` to ``end``.
+That holds for ``NULL_TRACER`` too: with the ring off it hands out a
+span that carries the annotation alone, and with no session recording
+it still hands out the shared no-op span after one cheap check.  An
+annotation is recorded on the thread that ends it, so spans ended on
+another thread (``request``, begun with ``same_thread=False``) and
+retroactive ``record`` spans stay in the ring only.
 """
 
 from __future__ import annotations
@@ -51,12 +67,35 @@ from typing import Optional
 
 _ids = itertools.count(1)      # process-wide: span ids unique across tracers
 
+PROFILER_PREFIX = "knn."       # how a trace reduction tells program spans
+_profiler = None               # annotation class of the profiler sink, or None
+
+
+def set_profiler_sink(annotation) -> None:
+    """Mirror same-thread spans into a profiler session from now on (see
+    module docstring): ``annotation(name, **attrs)`` is a context manager
+    class with a static ``is_enabled()``, as ``jax.profiler.
+    TraceAnnotation`` is.  None removes the sink."""
+    global _profiler
+    _profiler = annotation
+
+
+def _mirror(name: str, attrs: dict):
+    """The sink's annotation for a span begun now, entered; None when no
+    sink is installed or no profiler session records."""
+    ann = _profiler
+    if ann is None or not ann.is_enabled():
+        return None
+    m = ann(PROFILER_PREFIX + name, **attrs)
+    m.__enter__()
+    return m
+
 
 class Span:
     """One in-flight (or finished) span.  End it exactly once."""
 
     __slots__ = ("tracer", "name", "span_id", "trace_id", "parent_id",
-                 "t0", "t1", "attrs")
+                 "t0", "t1", "attrs", "mirror")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  trace_id: int, parent_id: Optional[int], t0: float,
@@ -69,11 +108,14 @@ class Span:
         self.t0 = t0
         self.t1: Optional[float] = None
         self.attrs = attrs
+        self.mirror = None        # the profiler sink's annotation, if any
 
     def end(self, **attrs) -> "Span":
         """Finish the span (idempotent: a second end is ignored)."""
         if self.t1 is None:
             self.t1 = time.perf_counter()
+            if self.mirror is not None:
+                self.mirror.__exit__(None, None, None)
             if attrs:
                 self.attrs.update(attrs)
             self.tracer._finish(self)
@@ -118,12 +160,34 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _MirrorSpan(_NullSpan):
+    """What the disabled plane hands out while a profiler session
+    records: the sink's annotation alone, no ring."""
+
+    __slots__ = ("mirror",)
+
+    def __init__(self, mirror):
+        self.mirror = mirror
+
+    def end(self, **attrs):
+        m, self.mirror = self.mirror, None
+        if m is not None:
+            m.__exit__(None, None, None)
+        return self
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+
 class Tracer:
     """Ring-buffer span recorder; see module docstring.
 
     Thread-safe: ``begin``/``record`` may race from the submitting
     thread, the micro-batcher, the maintenance worker, and mutators —
-    the ring append and the active-span accounting share one lock.
+    the ring append and the active-span accounting share one lock.  It
+    is re-entrant because the collector hook (obs/gcwatch.py) records
+    from inside a collection, which can run on a thread that holds it.
     """
 
     enabled = True
@@ -133,16 +197,17 @@ class Tracer:
             raise ValueError("capacity must be >= 1")
         self.capacity = int(capacity)
         self._ring: deque = deque(maxlen=self.capacity)
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._active = 0          # begun, not yet ended (torn-span probe)
         self.dropped = 0          # spans evicted by the ring
 
     # ---- producing spans -------------------------------------------------
 
     def begin(self, name: str, *, parent=None, t0: Optional[float] = None,
-              **attrs) -> Span:
+              same_thread: bool = True, **attrs) -> Span:
         """Start a span now (or at ``t0``).  ``parent`` is a Span (or
-        None to root a new trace)."""
+        None to root a new trace).  ``same_thread=False`` marks a span
+        that another thread ends: it stays out of the profiler sink."""
         sid = next(_ids)
         if parent is None or parent.span_id == 0:
             trace_id, parent_id = sid, None
@@ -150,6 +215,8 @@ class Tracer:
             trace_id, parent_id = parent.trace_id, parent.span_id
         span = Span(self, name, sid, trace_id, parent_id,
                     time.perf_counter() if t0 is None else t0, attrs)
+        if same_thread:
+            span.mirror = _mirror(name, attrs)
         with self._lock:
             self._active += 1
         return span
@@ -161,7 +228,8 @@ class Tracer:
     def record(self, name: str, t0: float, t1: float, *, parent=None,
                **attrs) -> Span:
         """Retroactive span: both endpoints already measured."""
-        span = self.begin(name, parent=parent, t0=t0, **attrs)
+        span = self.begin(name, parent=parent, t0=t0, same_thread=False,
+                          **attrs)
         span.t1 = t1
         self._finish(span)
         return span
@@ -219,17 +287,24 @@ class Tracer:
 class NullTracer:
     """The disabled plane: every producer call returns the shared no-op
     span.  No lock, no allocation — obs=off costs one attribute load and
-    one call per instrumentation point."""
+    one call per instrumentation point, plus the profiler sink's check
+    for a same-thread span (while a session records, such a span carries
+    the sink's annotation)."""
 
     enabled = False
     capacity = 0
     dropped = 0
 
-    def begin(self, name, *, parent=None, t0=None, **attrs):
+    def begin(self, name, *, parent=None, t0=None, same_thread=True,
+              **attrs):
+        if same_thread and _profiler is not None:
+            mirror = _mirror(name, attrs)
+            if mirror is not None:
+                return _MirrorSpan(mirror)
         return _NULL_SPAN
 
     def span(self, name, *, parent=None, **attrs):
-        return _NULL_SPAN
+        return self.begin(name, **attrs)
 
     def record(self, name, t0, t1, *, parent=None, **attrs):
         return _NULL_SPAN

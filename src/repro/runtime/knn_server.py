@@ -120,6 +120,8 @@ from repro.kernels import ops as kops
 from repro.kernels import routing as routing_mod
 from repro.obs import (BatchCapture, ContractAuditor, ExplainRecord,
                        ObsPlane, ShadowAuditor, SloEngine)
+from repro.obs import gcwatch
+from repro.obs import trace as obs_trace
 from repro.obs.export import ObsHttpServer
 from repro.obs.metrics import default_registry
 from repro.parallel.compat import make_mesh, shard_map
@@ -128,6 +130,11 @@ from repro.store import index as index_mod
 from repro.store import summaries as summaries_mod
 
 _ID_SENTINEL = 2**31 - 1
+
+# Same-thread spans of every tracer, the disabled one included, are also
+# annotations in a recording jax.profiler session (obs/trace.py), on the
+# profiler's clock beside the device's ops.
+obs_trace.set_profiler_sink(jax.profiler.TraceAnnotation)
 
 
 class QueryResult(NamedTuple):
@@ -508,13 +515,22 @@ class KnnServer:
         if store is not None:
             store.attach_obs(self.obs)
         reg = self.obs.metrics
+        # The host's time per dispatch, split so that what the serving
+        # thread neither ran nor waited on the device for (the GIL, the
+        # scheduler) is their difference: prologue_s + dispatch_s span
+        # chunk taken to resolve done, wait_s is the readback's block on
+        # the device, cpu_s the thread's CPU time over the same span;
+        # lock_wait_s is the snapshot stage's wait for the store lock.
         self._m = {
             "queued": reg.histogram("serve.queued_s"),
+            "prologue": reg.histogram("serve.prologue_s"),
             "snapshot": reg.histogram("serve.snapshot_s"),
             "route": reg.histogram("serve.route_s"),
             "kernel": reg.histogram("serve.kernel_s"),
+            "wait": reg.histogram("serve.wait_s"),
             "resolve": reg.histogram("serve.resolve_s"),
             "dispatch": reg.histogram("serve.dispatch_s"),
+            "cpu": reg.histogram("serve.cpu_s"),
             "latency": reg.histogram("serve.latency_s"),
             "rounds": reg.histogram("serve.rounds"),
             "messages": reg.histogram("serve.messages"),
@@ -522,6 +538,11 @@ class KnnServer:
             "cand_frac": reg.histogram("serve.candidate_fraction"),
             "errors": reg.counter("serve.dispatch_errors"),
         }
+        if store is not None:
+            self._m["lock_wait"] = reg.histogram("store.lock_wait_s")
+        # Collector pauses land in this registry while the server is
+        # open (obs/gcwatch.py); close() leaves.
+        gcwatch.HOOK.subscribe(self.obs)
         self._contract = ContractAuditor(reg, k=self.k)
         # The shadow replay audits whichever contract this server
         # serves: byte-identity for pruned exact routing, measured
@@ -809,11 +830,12 @@ class KnnServer:
         return self.obs.tracer.export_jsonl(path_or_file)
 
     def close(self) -> None:
-        """Quiesce the micro-batcher and release the exposition endpoint
-        (idempotent; servers without an endpoint just stop())."""
+        """Quiesce the micro-batcher, release the exposition endpoint and
+        stop counting collector pauses (idempotent)."""
         self.stop()
         if self._http is not None:
             self._http.close()
+        gcwatch.HOOK.unsubscribe(self.obs)
 
     def warmup(self):
         """Compile every bucket shape up front (one trace per bucket)."""
@@ -928,7 +950,8 @@ class KnnServer:
         t_enq = time.perf_counter()
         # Root span of this request's trace, opened at the enqueue
         # timestamp so the retroactive "queued" child always nests.
-        span = self.obs.tracer.begin("request", t0=t_enq, l=l)
+        span = self.obs.tracer.begin("request", t0=t_enq, l=l,
+                                     same_thread=False)
         rec = _Pending(query, l, t_enq, Future(), span)
         with self._cv:
             self._pending.append(rec)
@@ -996,33 +1019,31 @@ class KnnServer:
         return rounds, messages
 
     def _unpack_outputs(self, out):
-        """Host-side view of one executable's outputs: ``(d, i, iters,
+        """One executable's host outputs (``_run``) as ``(d, i, iters,
         surv, pred)`` where ``pred`` is the ``(label, confidence)`` pair
         when the config predicts and ``()`` otherwise (the executable's
         output arity follows the same flag)."""
         d, i, iters, surv = out[:4]
-        d, i = np.asarray(d), np.asarray(i)
-        surv, iters = np.asarray(surv), int(iters)
-        pred = tuple(np.asarray(x) for x in out[4:])
-        return d, i, iters, surv, pred
+        return d, i, int(iters), surv, tuple(out[4:])
 
-    def _ensemble_call(self, operands, active, q, l_arr, touched):
+    def _ensemble_call(self, kspan, operands, active, q, l_arr, touched):
         """Serve one micro-batch in ensemble mode: local-k split on the
-        host, one collective-free launch, host aggregation.
+        host, one collective-free launch (under ``kspan``, as ``_run``),
+        host aggregation.
 
-        Returns ``(d, i, iters, surv, pred, payload, votes, kl)`` shaped
-        like the exact path's outputs so the dispatch tail is shared:
-        ``d``/``i`` are all-sentinel (no point identity ever leaves its
-        shard — that is the mode's bill), ``payload`` the (k, B, C)
-        per-shard answers for the explain vote table, ``votes`` the
-        (B, C) shard-vote tally (classification only), ``kl`` the per-row
-        local-k actually used.
+        Returns ``(d, i, iters, surv, pred, payload, votes, kl, wait_s)``
+        shaped like the exact path's outputs so the dispatch tail is
+        shared: ``d``/``i`` are all-sentinel (no point identity ever
+        leaves its shard — that is the mode's bill), ``payload`` the
+        (k, B, C) per-shard answers for the explain vote table, ``votes``
+        the (B, C) shard-vote tally (classification only), ``kl`` the
+        per-row local-k actually used, ``wait_s`` the readback's block.
         """
         cfg = self.cfg
         kl = predict_mod.local_k_for(l_arr, touched, cfg.local_k,
                                      cfg.l_max)
         ops = operands if active is None else operands + (active,)
-        payload = np.asarray(self._ensemble_fn(*ops, q, kl))
+        payload, wait_s = self._run(kspan, self._ensemble_fn, *ops, q, kl)
         act = (np.ones(self.k, bool) if active is None
                else np.asarray(active, bool))
         if cfg.predict == "vote":
@@ -1034,9 +1055,29 @@ class KnnServer:
         d = np.full((b, cfg.l_max), np.inf, np.float32)
         i = np.full((b, cfg.l_max), _ID_SENTINEL, np.int32)
         surv = np.zeros(b, np.int32)
-        return d, i, 0, surv, (label, conf), payload, votes, kl
+        return d, i, 0, surv, (label, conf), payload, votes, kl, wait_s
+
+    def _run(self, kspan, fn, *args):
+        """One launch of ``fn(*args)`` and its readback, as ``kspan``'s
+        leaves ``kernel.launch`` (the call returning) and
+        ``kernel.readback``; returns the outputs as host arrays and the
+        seconds the readback blocked on the device."""
+        tracer = self.obs.tracer
+        with tracer.span("kernel.launch", parent=kspan):
+            out = fn(*args)
+        with tracer.span("kernel.readback", parent=kspan):
+            t0 = time.perf_counter()
+            jax.block_until_ready(out)
+            wait_s = time.perf_counter() - t0
+            out = (tuple(map(np.asarray, out))
+                   if isinstance(out, (tuple, list)) else np.asarray(out))
+        return out, wait_s
 
     def _dispatch(self, chunk: list[_Pending]):
+        tracer = self.obs.tracer
+        t_take = time.perf_counter()
+        cpu0 = time.thread_time()
+        pspan = tracer.begin("dispatch.prologue")
         n = len(chunk)
         bucket = self._bucket_for(n)
         q = np.zeros((bucket, self.dim), np.float32)
@@ -1051,7 +1092,7 @@ class KnnServer:
             batch_id = self._batch_counter
             self._batch_counter += 1
         key = jax.random.fold_in(self._base_key, batch_id)
-        tracer = self.obs.tracer
+        pspan.end()
         t_dispatch = time.perf_counter()
         # Per-batch trace root; request trees point at it through their
         # "serve" child's batch attribute (cross-tree reference by
@@ -1067,10 +1108,13 @@ class KnnServer:
             t_snap0 = time.perf_counter()
             sspan = tracer.begin("snapshot", parent=dspan, t0=t_snap0)
             batch_spans.append(sspan)
+            if self._store is not None:
+                waited0 = self._store.lock_wait_s()
             operands, generation, summ, idx = self._backing_arrays()
             if self._store is not None:
                 n_live = int(self._store.live_per_shard.sum())
                 maint0 = self._store.maint_commit_clock()
+                lock_wait = self._store.lock_wait_s() - waited0
             else:
                 n_live = self.m_local * self.k
                 maint0 = (0, None)
@@ -1097,17 +1141,17 @@ class KnnServer:
                 packed = self._packed_for(summ)
                 if self._indexed:
                     iops = self._index_ops_for(idx)
-                    *out, active, keep_any = self._route_fn(
-                        operands, packed, *iops, q, l_arr, key)
-                    keep_arr = np.asarray(keep_any).reshape(
-                        self.k, idx.num_buckets)
+                    (*out, active_arr, keep_any), wait_s = self._run(
+                        kspan, self._route_fn, operands, packed, *iops, q,
+                        l_arr, key)
+                    keep_arr = keep_any.reshape(self.k, idx.num_buckets)
                     cand_frac = index_mod.candidate_fraction(
                         idx, keep_arr)
                 else:
-                    *out, active = self._route_fn(operands, packed, q,
-                                                  l_arr, key)
+                    (*out, active_arr), wait_s = self._run(
+                        kspan, self._route_fn, operands, packed, q, l_arr,
+                        key)
                 d, i, iters, surv, pred = self._unpack_outputs(out)
-                active_arr = np.asarray(active)
                 touched = int(active_arr.sum())
                 kspan.end(touched=touched)
                 t_kern1 = time.perf_counter()
@@ -1145,12 +1189,12 @@ class KnnServer:
                                      route_compute="host", **kattrs)
                 batch_spans.append(kspan)
                 if self._ensemble:
-                    (d, i, iters, surv, pred, epayload, evotes,
-                     kl) = self._ensemble_call(operands, active, q,
-                                               l_arr, touched)
+                    (d, i, iters, surv, pred, epayload, evotes, kl,
+                     wait_s) = self._ensemble_call(kspan, operands, active,
+                                                   q, l_arr, touched)
                 else:
-                    out = self._fn(*operands, *extra, active, q, l_arr,
-                                   key)
+                    out, wait_s = self._run(kspan, self._fn, *operands,
+                                            *extra, active, q, l_arr, key)
                     d, i, iters, surv, pred = self._unpack_outputs(out)
                 kspan.end()
                 t_kern0, t_kern1 = t_route1, time.perf_counter()
@@ -1173,11 +1217,12 @@ class KnnServer:
                                      **kattrs)
                 batch_spans.append(kspan)
                 if self._ensemble:
-                    (d, i, iters, surv, pred, epayload, evotes,
-                     kl) = self._ensemble_call(operands, None, q, l_arr,
-                                               touched)
+                    (d, i, iters, surv, pred, epayload, evotes, kl,
+                     wait_s) = self._ensemble_call(kspan, operands, None,
+                                                   q, l_arr, touched)
                 else:
-                    out = self._fn(*operands, *extra, q, l_arr, key)
+                    out, wait_s = self._run(kspan, self._fn, *operands,
+                                            *extra, q, l_arr, key)
                     d, i, iters, surv, pred = self._unpack_outputs(out)
                 kspan.end()
                 t_kern1 = time.perf_counter()
@@ -1346,13 +1391,19 @@ class KnnServer:
         vspan.end()
         dspan.end(touched=touched, generation=generation)
         t_res1 = time.perf_counter()
+        cpu_s = time.thread_time() - cpu0
         m = self._m
+        m["prologue"].observe(t_dispatch - t_take)
         m["snapshot"].observe(t_snap1 - t_snap0)
+        if self._store is not None:
+            m["lock_wait"].observe(lock_wait)
         m["kernel"].observe(t_kern1 - t_kern0)
+        m["wait"].observe(wait_s)
         if t_route0 is not None:
             m["route"].observe(t_route1 - t_route0)
         m["resolve"].observe(t_res1 - t_res0)
         m["dispatch"].observe(t_res1 - t_dispatch)
+        m["cpu"].observe(cpu_s)
         m["rounds"].observe(rounds)
         m["messages"].observe(messages)
         # Defensive (satellite of the -1 sentinel fix): a negative
@@ -1452,11 +1503,14 @@ class KnnServer:
     def _serve_loop(self):
         linger = self.cfg.max_wait_ms / 1e3
         full = self.cfg.bucket_sizes[-1]
+        tracer = self.obs.tracer
         while True:
+            wspan = tracer.begin("batcher.wait")
             with self._cv:
                 while self._running and not self._pending:
                     self._cv.wait(timeout=0.1)
                 if not self._running:
+                    wspan.end()
                     return
                 # Linger: give the batch a chance to fill before paying a
                 # datastore pass for a mostly-padded bucket.
@@ -1466,6 +1520,7 @@ class KnnServer:
                     self._cv.wait(timeout=max(
                         deadline - time.perf_counter(), 1e-4))
                 chunk = self._take_chunk_locked()
+            wspan.end(n=len(chunk))
             if chunk:
                 self._dispatch(chunk)
 

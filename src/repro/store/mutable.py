@@ -74,6 +74,7 @@ and 11.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from typing import NamedTuple, Optional
@@ -141,6 +142,42 @@ class _Op:
     label: Optional[float] = None  # None on update = keep current label
 
 
+def _staging(method):
+    """Run a staging method (``insert``/``delete``/``update``) inside a
+    ``store.stage`` span, ``op`` naming the method."""
+    @functools.wraps(method)
+    def staged(self, *args, **kwargs):
+        with self._obs_tracer().span("store.stage", op=method.__name__):
+            return method(self, *args, **kwargs)
+    return staged
+
+
+class _ReadLock:
+    """The store lock as the serving path's readers take it: how long a
+    thread waited for it is added to that thread's running total, so a
+    dispatch can tell its lock waits from the rest of its snapshot
+    stage.  An uncontended acquire reads no clock."""
+
+    __slots__ = ("_lock", "_waited")
+
+    def __init__(self, lock):
+        self._lock = lock
+        self._waited = threading.local()
+
+    def __enter__(self):
+        if not self._lock.acquire(blocking=False):
+            t0 = time.perf_counter()
+            self._lock.acquire()
+            self._waited.s = self.waited() + time.perf_counter() - t0
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        return False
+
+    def waited(self) -> float:
+        return getattr(self._waited, "s", 0.0)
+
+
 class MutableStore:
     """Mutable sharded point store; see module docstring.
 
@@ -196,6 +233,7 @@ class MutableStore:
         self.stats = IngestStats()
 
         self._lock = threading.RLock()
+        self._read_lock = _ReadLock(self._lock)
         self._sharding = NamedSharding(self.mesh, P(axis_name))
 
         # Host mirrors — authoritative control plane; the device snapshot
@@ -322,8 +360,15 @@ class MutableStore:
         """(commit count, last commit info dict or None) — one lock
         acquisition, so a before/after pair brackets a dispatch
         consistently."""
-        with self._lock:
+        with self._read_lock:
             return self._maint_commits, self._last_maint_commit
+
+    def lock_wait_s(self) -> float:
+        """Seconds the calling thread has waited, in all, for the store
+        lock in the serving path's readers (``snapshot``,
+        ``routing_snapshot``, ``serving_snapshot``, ``live_per_shard``,
+        ``maint_commit_clock``); a dispatch differences it."""
+        return self._read_lock.waited()
 
     def close(self) -> None:
         """Stop the background maintenance worker (no-op when inline or
@@ -342,7 +387,7 @@ class MutableStore:
     def snapshot(self) -> StoreSnapshot:
         """The current generation (immutable; safe to compute against while
         newer generations land)."""
-        with self._lock:
+        with self._read_lock:
             return self._snap
 
     def routing_snapshot(self):
@@ -350,7 +395,7 @@ class MutableStore:
         the generation-coupling invariant: ``summaries.generation ==
         snapshot.generation`` always, so pruned routing can never consult
         metadata from a different epoch than the one that answers."""
-        with self._lock:
+        with self._read_lock:
             return self._snap, self._summaries
 
     def serving_snapshot(self):
@@ -359,7 +404,7 @@ class MutableStore:
         ``index.generation == summaries.generation ==
         snapshot.generation`` always (``index`` is None when the store
         was built with ``index_buckets=0``)."""
-        with self._lock:
+        with self._read_lock:
             return self._snap, self._summaries, self._frozen_index
 
     def summaries(self) -> summaries_mod.ShardSummaries:
@@ -435,7 +480,7 @@ class MutableStore:
     @property
     def live_per_shard(self) -> np.ndarray:
         """(k,) live points per shard — the balance the compactor defends."""
-        with self._lock:
+        with self._read_lock:
             return self._live.copy()
 
     def live_arrays(self) -> tuple[np.ndarray, np.ndarray]:
@@ -488,6 +533,7 @@ class MutableStore:
 
     # ---- write side (staging) -------------------------------------------
 
+    @_staging
     def insert(self, points, ids=None, values=None, labels=None) -> np.ndarray:
         """Stage point insertions; returns the assigned global ids.
 
@@ -547,6 +593,7 @@ class MutableStore:
             self._maybe_autoflush_locked()
             return ids.astype(np.int32)
 
+    @_staging
     def delete(self, ids) -> None:
         """Stage deletions by global id (KeyError if not live/staged).
         Atomic: one unknown id rejects the whole batch, staging nothing."""
@@ -565,6 +612,7 @@ class MutableStore:
             self._projected_live -= len(ids)
             self._maybe_autoflush_locked()
 
+    @_staging
     def update(self, ids, points, labels=None) -> None:
         """Stage in-place point overwrites (same id, same slot).
         ``labels`` (optional, requires ``with_labels``) overwrites the
@@ -617,6 +665,19 @@ class MutableStore:
 
     def _apply_locked(self, *, force_compact: bool) -> int:
         t_apply = time.perf_counter()
+        with self._obs_tracer().span("store.apply") as span:
+            gen, n_ops, repacked = self._apply_ops_locked(force_compact)
+            span.annotate(generation=gen, ops=n_ops, repacked=repacked)
+        t_done = time.perf_counter()
+        if self._obs is not None:
+            reg = self._obs.metrics
+            reg.histogram("store.apply_s").observe(t_done - t_apply)
+            reg.counter("store.applies").inc()
+            reg.gauge("store.live").set(self._projected_live)
+        return gen
+
+    def _apply_ops_locked(self, force_compact: bool) -> tuple:
+        """The apply itself: (new generation, ops applied, repacked)."""
         ops, self._pending = self._pending, []
         self._staged_state = {}
         touched: set[int] = set()
@@ -749,16 +810,7 @@ class MutableStore:
         self._record_history()
         if self._worker is not None:
             self._worker.notify()
-        t_done = time.perf_counter()
-        self._obs_tracer().record("store.apply", t_apply, t_done,
-                                  generation=gen, ops=len(ops),
-                                  repacked=repacked)
-        if self._obs is not None:
-            reg = self._obs.metrics
-            reg.histogram("store.apply_s").observe(t_done - t_apply)
-            reg.counter("store.applies").inc()
-            reg.gauge("store.live").set(self._projected_live)
-        return gen
+        return gen, len(ops), repacked
 
     def _upload_snapshot_locked(self, *, generation: int) -> StoreSnapshot:
         """Full upload of the mirrors as a fresh snapshot.

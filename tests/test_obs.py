@@ -21,6 +21,7 @@ What this suite pins, layer by layer:
 import io
 import json
 import math
+import statistics
 import threading
 import time
 
@@ -203,6 +204,68 @@ def test_null_tracer_is_inert():
     assert NULL_TRACER.active_count() == 0
     assert NULL_TRACER.export_jsonl(io.StringIO()) == 0
     assert NULL_TRACER.stats()["enabled"] is False
+
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs enters/exits."""
+
+    enabled = True
+    log: list = []
+
+    def __init__(self, name, **attrs):
+        self.name, self.attrs = name, attrs
+
+    @staticmethod
+    def is_enabled():
+        return _FakeAnnotation.enabled
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.attrs))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture()
+def fake_sink(monkeypatch):
+    from repro.obs import trace as trace_mod
+    _FakeAnnotation.log = []
+    _FakeAnnotation.enabled = True
+    monkeypatch.setattr(trace_mod, "_profiler", _FakeAnnotation)
+    return _FakeAnnotation
+
+
+@pytest.mark.parametrize("tracer", ["ring", "null"])
+def test_profiler_sink_mirrors_same_thread_spans(fake_sink, tracer):
+    """While a session records, same-thread spans of either tracer are
+    also ``knn.``-prefixed annotations, begin to end; cross-thread and
+    retroactive spans are not; with no session nothing is mirrored and
+    the disabled tracer hands out its shared no-op span."""
+    from repro.obs.trace import _NULL_SPAN
+    tr = Tracer(capacity=16) if tracer == "ring" else NULL_TRACER
+    with tr.span("kernel", bucket=4):
+        inner = tr.begin("kernel.launch")
+        inner.end()
+        inner.end()                       # a second end exits nothing
+    tr.begin("request", same_thread=False).end()
+    tr.record("queued", 0.0, 1.0)
+    assert fake_sink.log == [("enter", "knn.kernel", {"bucket": 4}),
+                             ("enter", "knn.kernel.launch", {}),
+                             ("exit", "knn.kernel.launch"),
+                             ("exit", "knn.kernel")]
+    fake_sink.enabled = False
+    fake_sink.log = []
+    sp = tr.begin("snapshot")
+    sp.end()
+    assert fake_sink.log == []
+    if tracer == "null":
+        assert sp is _NULL_SPAN
+    else:
+        assert [r["name"] for r in tr.spans()] == [
+            "kernel.launch", "kernel", "request", "queued", "snapshot"]
+        assert tr.active_count() == 0
 
 
 def test_build_trees_rejects_malformed_forests():
@@ -537,9 +600,14 @@ def test_racing_span_forest_well_formed(mesh8):
 def test_instrumentation_overhead_within_budget(mesh8):
     """Tracing + contract auditing must cost <= 10% of obs-off
     throughput on the smoke workload (DESIGN.md §12 budget).  The arms
-    run the identical seeded load *interleaved* (back-to-back arms
-    confound the recorder's microseconds with scheduler drift), and
-    min-of-7 per arm damps the remaining noise."""
+    run the identical seeded load *interleaved*, in alternating order,
+    and each pass is read as the process's CPU time: the serving
+    thread's and the XLA threads' work, which is what throughput costs,
+    without the time spent waiting for a core that the other test
+    workers sharing the machine hold (the wall clock's reading swings
+    by tens of percent under them).  The overhead is the median of the
+    per-round on/off ratios.  Both arms carry the always-on clocks, the
+    collector hook and the profiler sink's check."""
     servers = {}
     for obs_trace in (False, True):
         srv, centers = _clustered_server(mesh8, obs_trace=obs_trace,
@@ -550,16 +618,215 @@ def test_instrumentation_overhead_within_budget(mesh8):
                 .astype(np.float32) for w in range(6)]
 
     def one_pass(srv):
-        t0 = time.perf_counter()
+        t0 = time.process_time()
         for qs in qs_waves:
             srv.query_batch(qs, [4] * 4)
-        return time.perf_counter() - t0
+        return time.process_time() - t0
 
     for srv in servers.values():       # warm the whole path, both arms
         one_pass(srv)
-    best = {False: math.inf, True: math.inf}
-    for _ in range(7):
-        for obs_trace, srv in servers.items():
-            best[obs_trace] = min(best[obs_trace], one_pass(srv))
-    overhead = (best[True] - best[False]) / best[False]
+    ratios = []
+    for r in range(15):
+        order = (False, True) if r % 2 == 0 else (True, False)
+        cost = {obs_trace: one_pass(servers[obs_trace])
+                for obs_trace in order}
+        ratios.append(cost[True] / cost[False])
+    for srv in servers.values():
+        srv.close()
+    overhead = statistics.median(ratios) - 1.0
     assert overhead <= 0.10, f"obs overhead {overhead:.1%} > 10%"
+
+
+# ---- profiler clock, collector pauses, host counters ---------------------
+
+def _store_server(mesh8, *, obs_trace=False):
+    cfg = CONFIG.replace(dim=DIM, l=4, l_max=L_MAX, bucket_sizes=(1, 2, 4),
+                         max_wait_ms=1.0, store_capacity_per_shard=32,
+                         obs_trace=obs_trace)
+    store = MutableStore(DIM, mesh=mesh8, axis_name="x",
+                         **cfg.store_kwargs())
+    rng = np.random.default_rng(21)
+    store.insert(rng.normal(size=(64, DIM)).astype(np.float32))
+    store.flush()
+    srv = KnnServer(store=store, cfg=cfg)
+    srv.warmup()
+    return srv, store, rng
+
+
+def _host_spans(log_dir):
+    """{line id: [(name, start_ns, end_ns)]} of the ``knn.`` events on
+    the host plane of the one trace under ``log_dir``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, ln in enumerate(plane.lines):
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in ln.events if e.name.startswith("knn.")]
+            if evs:
+                lines[i] = sorted(evs, key=lambda e: e[1])
+    return lines
+
+
+def test_profiler_session_holds_serving_and_write_spans(mesh8, tmp_path):
+    """With the ring off, a recording jax.profiler session gets the
+    serving thread's leaves and the writer's spans on the host plane,
+    ``knn.``-prefixed; the serving thread's leaves follow one another
+    without overlapping, and the cross-thread request span is absent."""
+    import jax
+    srv, store, rng = _store_server(mesh8)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with srv.serving():
+            futs = [srv.submit(q, 4) for q in
+                    rng.normal(size=(3, DIM)).astype(np.float32)]
+            for f in futs:
+                f.result(timeout=120)
+        srv.insert(rng.normal(size=(4, DIM)).astype(np.float32))
+        srv.flush_store()
+    finally:
+        jax.profiler.stop_trace()
+        srv.close()
+    lines = _host_spans(str(tmp_path))
+    names = {e[0] for evs in lines.values() for e in evs}
+    assert {"knn.batcher.wait", "knn.dispatch.prologue", "knn.dispatch",
+            "knn.snapshot", "knn.kernel", "knn.kernel.launch",
+            "knn.kernel.readback", "knn.resolve", "knn.store.stage",
+            "knn.store.apply"} <= names
+    assert "knn.request" not in names
+    serving, = [evs for evs in lines.values()
+                if any(e[0] == "knn.batcher.wait" for e in evs)]
+    leaves = [e for e in serving if e[0] in (
+        "knn.batcher.wait", "knn.dispatch.prologue", "knn.snapshot",
+        "knn.kernel.launch", "knn.kernel.readback", "knn.resolve")]
+    assert len(leaves) >= 6
+    for prev, nxt in zip(leaves, leaves[1:]):
+        assert nxt[1] >= prev[2], (prev, nxt)
+
+
+def test_collector_pauses_counted_while_the_server_is_open(mesh8):
+    """A forced full collection under an open server is one more
+    collection and one more pause in its registry, and a ``gc`` span of
+    generation 2 in its ring; close() takes the server's plane off the
+    hook."""
+    import gc
+    from repro.obs import gcwatch
+    srv, _ = _clustered_server(mesh8, obs_trace=True, seed=8)
+    reg = srv.obs.metrics
+    n0 = reg.value("runtime.gc_collections")
+    p0 = reg.histogram("runtime.gc_pause_s").count
+    assert gcwatch.HOOK.installed and gcwatch.HOOK.subscribers() >= 1
+    gc.collect()
+    assert reg.value("runtime.gc_collections") >= n0 + 1
+    pauses = reg.histogram("runtime.gc_pause_s")
+    assert pauses.count >= p0 + 1 and pauses.total > 0
+    spans = [r for r in srv.obs.tracer.spans() if r["name"] == "gc"]
+    assert spans and spans[-1]["attrs"]["generation"] == 2
+    srv.close()
+    srv.close()                               # idempotent
+    assert gcwatch.HOOK.installed == (gcwatch.HOOK.subscribers() > 0)
+    count = pauses.count
+    gc.collect()
+    assert pauses.count == count
+
+
+def test_collector_hook_leaves_with_its_last_subscriber():
+    """The hook is installed by the first subscriber and removed by the
+    last; a plane dropped without unsubscribing falls out."""
+    import gc
+    from repro.obs import gcwatch
+    hook = gcwatch.GcHook()
+    a, b = ObsPlane(), ObsPlane()
+    hook.subscribe(a)
+    hook.subscribe(b)
+    hook.subscribe(b)
+    gc.disable()                  # only the collections asked for below
+    try:
+        assert hook.installed and hook.subscribers() == 2
+        gc.collect(1)
+        for reg in (a.metrics, b.metrics):
+            assert reg.value("runtime.gc_collections") == 1
+            assert reg.histogram("runtime.gc_pause_s").count == 1
+        gc.collect(0)
+        assert a.metrics.value("runtime.gc_collections") == 2
+        assert a.metrics.histogram("runtime.gc_pause_s").count == 1
+        hook.unsubscribe(a)
+        assert hook.installed and hook.subscribers() == 1
+        del b, reg
+        gc.collect()
+        hook.unsubscribe(a)                   # publishes: b is gone
+        assert not hook.installed and hook.subscribers() == 0
+    finally:
+        gc.enable()
+        if hook.installed:
+            gc.callbacks.remove(hook._callback)
+
+
+def test_host_counters_fill_with_tracing_off(mesh8):
+    """prologue_s, wait_s, cpu_s and the store's lock_wait_s get one
+    observation per dispatch with the ring off; a reader held off the
+    store lock by a writer counts the wait, and the serving thread's
+    CPU time stays within its wall time."""
+    srv, store, rng = _store_server(mesh8)
+    reg = srv.obs.metrics
+    names = ("serve.prologue_s", "serve.wait_s", "serve.cpu_s",
+             "store.lock_wait_s", "serve.dispatch_s")
+    before = {n: reg.histogram(n).count for n in names}
+    srv.query_batch(rng.normal(size=(3, DIM)).astype(np.float32), [4] * 3)
+    held, release = threading.Event(), threading.Event()
+
+    def writer():
+        with store._lock:
+            held.set()
+            release.wait(timeout=10)
+
+    t = threading.Thread(target=writer, daemon=True)
+    t.start()
+    assert held.wait(timeout=10)
+    timer = threading.Timer(0.2, release.set)
+    timer.start()
+    srv.query_batch(rng.normal(size=(2, DIM)).astype(np.float32), [4] * 2)
+    t.join(timeout=10)
+    assert not t.is_alive()
+    srv.close()
+    snap = reg.snapshot()
+    for n in names:
+        assert snap[n]["count"] == before[n] + 2, n
+    assert snap["store.lock_wait_s"]["max"] >= 0.1
+    wall = snap["serve.prologue_s"]["sum"] + snap["serve.dispatch_s"]["sum"]
+    assert 0 < snap["serve.cpu_s"]["sum"] <= wall
+    assert 0 < snap["serve.wait_s"]["sum"] <= snap["serve.dispatch_s"]["sum"]
+
+
+def test_dispatch_allocates_no_span_with_tracing_off(mesh8, monkeypatch):
+    """Ring off and no profiler session: every span the serving and
+    write paths ask for is the shared no-op span, so a dispatch builds
+    no span object."""
+    from repro.obs import trace as trace_mod
+    handed = []
+    begin = trace_mod.NullTracer.begin
+
+    def counting_begin(self, name, **kw):
+        sp = begin(self, name, **kw)
+        handed.append((name, sp))
+        return sp
+
+    srv, _, rng = _store_server(mesh8)
+    monkeypatch.setattr(trace_mod.NullTracer, "begin", counting_begin)
+    with srv.serving():
+        f = srv.submit(rng.normal(size=DIM).astype(np.float32), 4)
+        assert f.result(timeout=120).ids.shape == (4,)
+    srv.query_batch(rng.normal(size=(2, DIM)).astype(np.float32), [4] * 2)
+    srv.insert(rng.normal(size=(2, DIM)).astype(np.float32))
+    srv.flush_store()
+    srv.close()
+    names = {name for name, _ in handed}
+    assert {"request", "batcher.wait", "dispatch.prologue", "dispatch",
+            "snapshot", "kernel", "kernel.launch", "kernel.readback",
+            "resolve", "store.stage", "store.apply"} <= names
+    assert all(sp is trace_mod._NULL_SPAN for _, sp in handed)
