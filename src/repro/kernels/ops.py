@@ -2,7 +2,9 @@
 
 Responsibilities:
   * pad arbitrary shapes up to block multiples (+inf-padding points so padded
-    rows never win a top-l slot), slice results back;
+    rows never win a top-l slot), slice results back — except the L2
+    distance's points, which the kernel reads as they lie on the device
+    (``points_transposed``, ``_l2_blocks``);
   * route to the jnp oracle when a shape is outside a kernel's
     specialization envelope (l > MAX_L, VMEM budget exceeded) or when the
     backend has no Mosaic support (this CPU container -> interpret mode for
@@ -52,15 +54,75 @@ def _pad_to(x, mult, axis, value):
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _sublanes(dtype) -> int:
+    """Rows of one (sublane, lane) VMEM tile: 8 for 32-bit, 16 for bf16."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def points_transposed(m: int, d: int) -> bool:
+    """Whether the L2 kernel reads an (m, d) point buffer as its (d, m)
+    view: true where the TPU keeps the buffer column-major.
+
+    The device lays a 2-d array out in (8, 128) tiles along whichever
+    orientation pads it less (d = 100: 104 sublanes against 128 lanes;
+    d = 128: no pad either way, row-major kept), so ``p.T`` is a free
+    view exactly when this holds.  ``tests/test_tpu_compile.py`` checks
+    the rule against the compiler's own choice.
+    """
+    cols = _ceil_mult(d, 8) * _ceil_mult(m, 128)
+    rows = _ceil_mult(m, 8) * _ceil_mult(d, 128)
+    return cols < rows
+
+
+def _l2_vmem(bb, bm, bk, p_itemsize, cols):
+    """VMEM estimate of one L2 grid step: double-buffered q, point and
+    output tiles, three f32 working copies of the point tile and the
+    (bb, bm) products.  Conservative: Mosaic compiles v5e tiles this puts
+    at about twice the budget."""
+    q_tile = bb * _ceil_mult(bk, 128) * 4
+    p_elems = (_ceil_mult(bk, 8) * bm if cols
+               else _ceil_mult(bm, 8) * _ceil_mult(bk, 128))
+    out_tile = bb * _ceil_mult(bm, 128) * 4
+    return (2 * q_tile + p_elems * (2 * p_itemsize + 3 * 4)
+            + 4 * out_tile)
+
+
+_L2_MAX_BLOCK_B = 256
+_L2_BLOCK_M = (4096, 2048, 1024, 512, 256, 128)
+_L2_WIDE_BLOCK_K = 512
+
+
+def _l2_blocks(B, m, d, q_dtype, p_dtype, cols):
+    """(block_b, block_m, block_k) for one L2 call: the query block is the
+    batch rounded up to the sublane multiple (split only past 256 rows),
+    the width one block whenever the tiles fit ``_VMEM_BUDGET``, and
+    block_m the largest that fits (m itself when m is smaller)."""
+    sub = _sublanes(q_dtype)
+    b_pad = _ceil_mult(max(B, 1), sub)
+    nb = -(-b_pad // _L2_MAX_BLOCK_B)
+    bb = _ceil_mult(-(-b_pad // nb), sub)
+    item = jnp.dtype(p_dtype).itemsize
+    for bk in (d, _L2_WIDE_BLOCK_K):
+        for bm in _L2_BLOCK_M:
+            bm = min(bm, m)
+            if _l2_vmem(bb, bm, bk, item, cols) <= _VMEM_BUDGET:
+                return bb, bm, bk
+    return bb, min(128, m), _L2_WIDE_BLOCK_K
+
+
 @functools.partial(jax.jit, static_argnames=("block_b", "block_m", "block_k",
-                                              "interpret"))
-def _l2_padded(q, p, block_b, block_m, block_k, interpret):
-    B, m = q.shape[0], p.shape[0]
+                                              "cols", "interpret"))
+def _l2_padded(q, p, block_b, block_m, block_k, cols, interpret):
+    """Pads the queries (and, past the VMEM budget, the width) only: the
+    points go in as they lie, viewed as (d, m) in the cols form."""
+    B = q.shape[0]
     qp = _pad_to(_pad_to(q, block_b, 0, 0.0), block_k, 1, 0.0)
-    pp = _pad_to(_pad_to(p, block_m, 0, 0.0), block_k, 1, 0.0)
-    out = _l2.l2_distance(qp, pp, block_b=block_b, block_m=block_m,
-                          block_k=block_k, interpret=interpret)
-    return out[:B, :m]
+    pv = p.T if cols else p
+    pv = _pad_to(pv, block_k, 0 if cols else 1, 0.0)
+    out = _l2.l2_distance(qp, pv, block_b=block_b, block_m=block_m,
+                          block_k=block_k, points_transposed=cols,
+                          interpret=interpret)
+    return out[:B]
 
 
 def l2_distance(queries, points, *, valid=None, block_b=None, block_m=None,
@@ -78,10 +140,14 @@ def l2_distance(queries, points, *, valid=None, block_b=None, block_m=None,
         if valid is not None:
             return ref.masked_l2_distance_ref(queries, points, valid)
         return ref.l2_distance_ref(queries, points)
-    bb = block_b or _l2.DEFAULT_BLOCK_B
-    bm = block_m or _l2.DEFAULT_BLOCK_M
-    bk = block_k or _l2.DEFAULT_BLOCK_K
-    out = _l2_padded(queries, points, bb, bm, bk, mode == "interpret")
+    m, d = points.shape
+    cols = points_transposed(m, d)
+    bb, bm, bk = _l2_blocks(queries.shape[0], m, d, queries.dtype,
+                            points.dtype, cols)
+    _obs_metrics.default_registry().counter(
+        f"kernel.l2_distance.form.{'cols' if cols else 'rows'}").inc()
+    out = _l2_padded(queries, points, block_b or bb, min(block_m or bm, m),
+                     block_k or bk, cols, mode == "interpret")
     if valid is not None:
         out = jnp.where(valid[None, :].astype(jnp.bool_), out, jnp.inf)
     return out

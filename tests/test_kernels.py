@@ -40,6 +40,26 @@ def test_l2_distance_sweep(rng, shape, dtype):
                                **_tol(dtype))
 
 
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("m", [96, 777, 2048])
+@pytest.mark.parametrize("B", [1, 13, 32])
+@pytest.mark.parametrize("d", [1, 64, 100, 128, 200, 768])
+def test_l2_distance_forms(rng, d, B, m, dtype):
+    """Both point orientations (rows, and the (d, m) view of a
+    column-major buffer), full-width blocks, the query block rounded to
+    the sublane multiple and ragged last point blocks, against the plain
+    and the masked oracle."""
+    q = rng.normal(size=(B, d)).astype(np.float32).astype(dtype)
+    p = rng.normal(size=(m, d)).astype(np.float32).astype(dtype)
+    valid = rng.random(m) < 0.7
+    np.testing.assert_allclose(np.asarray(ops.l2_distance(q, p)),
+                               np.asarray(ref.l2_distance_ref(q, p)),
+                               **_tol(dtype))
+    np.testing.assert_allclose(
+        np.asarray(ops.l2_distance(q, p, valid=valid)),
+        np.asarray(ref.masked_l2_distance_ref(q, p, valid)), **_tol(dtype))
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("l", [1, 16, 100])
@@ -82,16 +102,24 @@ def test_direct_kernel_blocks(rng):
     q = rng.normal(size=(16, 256)).astype(np.float32)
     p = rng.normal(size=(512, 256)).astype(np.float32)
     for bb, bm, bk in [(8, 128, 128), (16, 256, 256), (8, 512, 128)]:
-        out = l2_kernel(q, p, block_b=bb, block_m=bm, block_k=bk,
-                        interpret=True)
-        np.testing.assert_allclose(np.asarray(out),
-                                   np.asarray(ref.l2_distance_ref(q, p)),
-                                   rtol=1e-4, atol=1e-3)
+        for pv, cols in ((p, False), (p.T, True)):
+            out = l2_kernel(q, pv, block_b=bb, block_m=bm, block_k=bk,
+                            points_transposed=cols, interpret=True)
+            np.testing.assert_allclose(np.asarray(out),
+                                       np.asarray(ref.l2_distance_ref(q, p)),
+                                       rtol=1e-4, atol=1e-3)
         v, i = dtk_kernel(q, p, 16, block_b=bb, block_m=bm, block_k=bk,
                           interpret=True)
         rv, _ = ref.distance_topk_ref(q, p, 16)
         np.testing.assert_allclose(np.asarray(v), np.asarray(rv),
                                    rtol=1e-4, atol=1e-3)
+    # a width split into k blocks, padded to a whole number of them
+    q = rng.normal(size=(5, 300)).astype(np.float32)
+    for m in (96, 1000):           # the rows form, then the cols form
+        p = rng.normal(size=(m, 300)).astype(np.float32)
+        np.testing.assert_allclose(
+            np.asarray(ops.l2_distance(q, p, block_k=128)),
+            np.asarray(ref.l2_distance_ref(q, p)), rtol=1e-4, atol=1e-3)
     x = rng.normal(size=(8, 1024)).astype(np.float32)
     for bb, bm in [(8, 256), (4, 512)]:
         v, i = ltk_kernel(x, 16, block_b=bb, block_m=bm, interpret=True)

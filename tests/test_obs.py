@@ -461,6 +461,9 @@ def test_request_trace_complete_and_audits_clean(mesh8):
     # the kernels dispatcher counted its envelope builds (and any
     # fallbacks) in the process-wide registry
     assert default_registry().value("kernel.envelopes") > 0
+    # and the L2 kernel's form: 24 points a shard at width 8 lie
+    # column-major on a TPU, so the kernel reads their (d, m) view
+    assert snap["kernel"]["kernel.l2_distance.form.cols"] > 0
 
 
 def test_device_routed_trace_has_fused_route_span(mesh8):
