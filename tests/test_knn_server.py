@@ -416,3 +416,59 @@ def test_touched_sentinel_never_served_or_observed(mesh8, pts, route):
     hist = srv.obs.metrics.get("serve.touched_shards").snapshot()
     assert hist["count"] == s["batches"]
     assert hist["min"] >= 0
+
+
+READBACK_FORMS = {
+    "selection": dict(),
+    "gather": dict(sampler="gather"),
+    "pruned_host": dict(route="pruned", route_compute="host"),
+    "device_route": dict(route="pruned", route_compute="device"),
+    "approx": dict(search="approx", index_buckets=4),
+    "exact_predict": dict(predict="vote", num_classes=4),
+    "ensemble": dict(predict="vote", num_classes=4,
+                     predict_mode="ensemble"),
+}
+
+
+@pytest.mark.parametrize("form", list(READBACK_FORMS))
+def test_readback_matches_plain_reads(mesh8, pts, monkeypatch, form):
+    """The overlapped readback (every output's copy started as the launch
+    returns, all read after one wait) hands the dispatch the same host
+    arrays as a plain ``np.asarray`` per output: every QueryResult field
+    it feeds is bit-identical, for each executable form the server
+    builds.  The same batch is served twice by one server at the same
+    key (the batch counter reset) and generation, once per readback."""
+    from repro.runtime import knn_server as ks
+    knobs = READBACK_FORMS[form]
+    labels = (np.random.default_rng(5).integers(0, 4, N).astype(np.float32)
+              if "predict" in knobs else None)
+    srv = KnnServer(pts, labels=labels, mesh=mesh8, axis_name="x",
+                    cfg=CONFIG.replace(dim=DIM, l_max=16, bucket_sizes=(4,),
+                                       **knobs))
+    qs = np.random.default_rng(6).normal(size=(3, DIM)).astype(np.float32)
+    ls = [1, 5, 16]
+    overlapped = srv.query_batch(qs, ls)
+    copies = srv.obs.metrics.histogram("serve.copy_s").count
+
+    def plain(out):
+        def collect():
+            host = (tuple(np.asarray(x) for x in out)
+                    if isinstance(out, tuple) else np.asarray(out))
+            return host, 0.0, 0.0
+        return collect
+
+    monkeypatch.setattr(ks, "_readback", plain)
+    monkeypatch.setattr(ks._OneBuffer, "__call__",
+                        lambda self, *args: self.inner(*args))
+    srv._batch_counter = 0
+    reads = srv.query_batch(qs, ls)
+    srv.close()
+    assert copies == 1
+    for a, b in zip(overlapped, reads):
+        assert a.dists.tobytes() == b.dists.tobytes()
+        assert a.ids.tobytes() == b.ids.tobytes()
+        assert (a.survivors, a.iterations) == (b.survivors, b.iterations)
+        assert (a.label, a.confidence) == (b.label, b.confidence)
+        assert a.generation == b.generation
+    if "predict" in knobs:
+        assert all(r.label is not None for r in overlapped)

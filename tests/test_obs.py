@@ -806,6 +806,42 @@ def test_host_counters_fill_with_tracing_off(mesh8):
     assert 0 < snap["serve.wait_s"]["sum"] <= snap["serve.dispatch_s"]["sum"]
 
 
+def test_readback_copy_counted_once_per_dispatch(mesh8):
+    """Every dispatch observes ``serve.copy_s`` once, beside
+    ``serve.wait_s``; the wait and the copy after it fit inside the
+    dispatch's ``serve.kernel_s``; and ``kernel.launch`` then
+    ``kernel.readback`` are the spans under each ``kernel``."""
+    srv, centers = _clustered_server(mesh8, seed=5)
+    reg = srv.obs.metrics
+    names = ("serve.copy_s", "serve.wait_s", "serve.kernel_s")
+    rng = np.random.default_rng(4)
+    for wave in range(4):
+        before = {n: (reg.histogram(n).count, reg.histogram(n).total)
+                  for n in names}
+        qs = (centers[wave % len(centers)]
+              + rng.normal(size=(3, DIM))).astype(np.float32)
+        srv.query_batch(qs, [4] * 3)
+        delta = {n: (reg.histogram(n).count - before[n][0],
+                     reg.histogram(n).total - before[n][1])
+                 for n in names}
+        assert all(c == 1 for c, _ in delta.values()), delta
+        assert delta["serve.copy_s"][1] >= 0
+        assert (delta["serve.wait_s"][1] + delta["serve.copy_s"][1]
+                <= delta["serve.kernel_s"][1])
+    recs = srv.obs.tracer.spans()
+    srv.close()
+    kids = {}
+    for r in recs:
+        kids.setdefault(r["parent"], []).append(r)
+    kernels = [r for r in recs if r["name"] == "kernel"]
+    assert len(kernels) == 4
+    for k in kernels:
+        launch, readback = sorted(kids[k["span"]], key=lambda r: r["t0"])
+        assert (launch["name"], readback["name"]) == (
+            "kernel.launch", "kernel.readback")
+        assert launch["t1"] <= readback["t0"]
+
+
 def test_dispatch_allocates_no_span_with_tracing_off(mesh8, monkeypatch):
     """Ring off and no profiler session: every span the serving and
     write paths ask for is the shared no-op span, so a dispatch builds
