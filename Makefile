@@ -129,4 +129,3 @@ perf-history:
 
 examples:
 	$(PYTHONPATH_PREFIX) python examples/quickstart.py
-	$(PYTHONPATH_PREFIX) python examples/knn_lm_serve.py

@@ -1,8 +1,8 @@
 """Shared benchmark plumbing.
 
-The k-machine-model benchmarks need k >= 2 host devices; like
-launch/dryrun.py (which claims 512), the benchmark entrypoint claims its
-own process-local device count — nothing leaks into tests or other runs.
+The k-machine-model benchmarks need k >= 2 host devices; the benchmark
+entrypoint claims its own process-local device count — nothing leaks
+into tests or other runs.
 """
 
 from __future__ import annotations

@@ -2,12 +2,12 @@
 
 Mirrors the paper's experimental setup (Section 3): synthetic points
 distributed over the mesh, scalar or d-dimensional, query broadcast,
-answer = l nearest.  Used by examples/quickstart.py, launch/serve.py
---arch knn-service, and — via the ``service_*`` fields — the micro-batched
-query service in runtime/knn_server.py.  This dataclass is the single
-source of service tuning: bucket shapes, selection knobs, and the
-selection-vs-gather A/B switch all live here (benchmarks/bench_serve.py
-sweeps them; nothing else hard-codes a service parameter).
+answer = l nearest.  Used by examples/quickstart.py and by the
+micro-batched query service in runtime/knn_server.py.  This dataclass is
+the single source of service tuning: bucket shapes, selection knobs, and
+the selection-vs-gather A/B switch all live here
+(benchmarks/bench_serve.py sweeps them; nothing else hard-codes a
+service parameter).
 """
 
 import dataclasses
@@ -15,13 +15,9 @@ import dataclasses
 
 @dataclasses.dataclass(frozen=True)
 class KnnServiceConfig:
-    name: str = "knn-service"
-    n_points: int = 1 << 22          # paper: 2^22 points per process
     dim: int = 64                    # paper uses scalars; dim=1 reproduces it
     l: int = 128                     # neighbors per query
-    query_batch: int = 8
     num_classes: int = 16            # for the classification head
-    value_range: float = 4294967295.0  # paper: U[0, 2^32 - 1]
 
     # ---- micro-batched query service (runtime/knn_server.py) ------------
     # Incoming requests are coalesced into one of these device batch shapes

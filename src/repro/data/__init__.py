@@ -1,9 +1,6 @@
-from repro.data.synthetic import (MarkovTokens, uniform_points,
-                                  gaussian_clusters, sharded_clusters,
-                                  drifting_clusters, labeled_mixture,
-                                  bayes_labels)
-from repro.data.pipeline import Prefetcher, lm_batch_specs
+from repro.data.synthetic import (uniform_points, gaussian_clusters,
+                                  sharded_clusters, drifting_clusters,
+                                  labeled_mixture, bayes_labels)
 
-__all__ = ["MarkovTokens", "uniform_points", "gaussian_clusters",
-           "sharded_clusters", "drifting_clusters", "labeled_mixture",
-           "bayes_labels", "Prefetcher", "lm_batch_specs"]
+__all__ = ["uniform_points", "gaussian_clusters", "sharded_clusters",
+           "drifting_clusters", "labeled_mixture", "bayes_labels"]

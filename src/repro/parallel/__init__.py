@@ -1,4 +1,4 @@
-"""Mesh/collective helpers shared by the core algorithms and the model stack."""
+"""Mesh/collective helpers shared by the core algorithms and the service."""
 
 from repro.parallel.collectives import replicate
 

@@ -1,15 +1,12 @@
 """One import site for the mesh / shard_map API surface.
 
-Thin aliases over the installed jax (>= 0.9): ``jax.shard_map``,
-``jax.make_mesh`` with explicit ``Auto`` axis types, ``jax.set_mesh`` and
-the abstract ambient mesh.  Call sites across the core algorithms, the
-serving runtime, benchmarks and tests import these names from here, so a
-future API rename is absorbed in one file.
+Thin aliases over the installed jax (>= 0.9): ``jax.shard_map`` and
+``jax.make_mesh`` with explicit ``Auto`` axis types.  Call sites across
+the core algorithms, the serving runtime, benchmarks and tests import
+these names from here, so a future API rename is absorbed in one file.
 """
 
 from __future__ import annotations
-
-import contextlib
 
 import jax
 from jax.sharding import AxisType
@@ -27,23 +24,3 @@ def make_mesh(axis_shapes, axis_names):
     return jax.make_mesh(axis_shapes, axis_names,
                          axis_types=(AxisType.Auto,) * len(tuple(axis_names)))
 
-
-def ambient_mesh_axis_names():
-    """Axis names of the mesh installed by :func:`set_mesh`, or None."""
-    mesh = jax.sharding.get_abstract_mesh()
-    return None if mesh.empty else set(mesh.axis_names)
-
-
-def ambient_mesh_axis_sizes():
-    """{axis name: size} of the ambient mesh, or None if no mesh is set."""
-    mesh = jax.sharding.get_abstract_mesh()
-    return None if mesh.empty else dict(zip(mesh.axis_names,
-                                            mesh.axis_sizes))
-
-
-def set_mesh(mesh):
-    """Context manager binding ``mesh`` as the ambient mesh; ``None``
-    means "no mesh"."""
-    if mesh is None:
-        return contextlib.nullcontext()
-    return jax.set_mesh(mesh)

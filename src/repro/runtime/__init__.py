@@ -1,10 +1,5 @@
-from repro.runtime.trainer import (TrainConfig, make_train_step,
-                                   init_opt_state, train_loop,
-                                   SimulatedNodeFailure)
-from repro.runtime.server import Server, ServeConfig
 from repro.runtime.knn_server import KnnServer, QueryResult, ServerStats
 from repro.runtime.metrics import MetricLogger, StepWatchdog
 
-__all__ = ["TrainConfig", "make_train_step", "init_opt_state", "train_loop",
-           "SimulatedNodeFailure", "Server", "ServeConfig", "KnnServer",
-           "QueryResult", "ServerStats", "MetricLogger", "StepWatchdog"]
+__all__ = ["KnnServer", "QueryResult", "ServerStats", "MetricLogger",
+           "StepWatchdog"]

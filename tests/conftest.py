@@ -1,10 +1,7 @@
 """Shared fixtures for the test suite.
 
 Device count: the k-machine-model tests (selection / knn / topk) need a
-multi-shard mesh, so we ask the CPU platform for 8 placeholder devices —
-deliberately NOT the dry-run's 512 (launch/dryrun.py sets its own flag in
-its own process; smoke tests here are mesh-free and indifferent to the
-host device count).
+multi-shard mesh, so we ask the CPU platform for 8 placeholder devices.
 """
 
 import os

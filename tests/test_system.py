@@ -1,84 +1,20 @@
-"""End-to-end behaviour tests: the paper's pipeline inside the LM stack.
+"""End-to-end behaviour test: the paper's l-NN classification path.
 
-1. kNN-LM serving: a reduced LM decodes with its logits interpolated
-   against a sharded datastore retrieved via Algorithm 2 — every piece of
-   the paper (local top-l, sample-prune, distributed selection, sparse
-   combine) in one running system.
-2. Distributed-selection sampler end-to-end under a (data, model) mesh.
-3. The standalone l-NN service path used by launch/serve.py.
+The standalone service path: Algorithm 2 over a sharded point set, with
+the winners' labels voted into a class prediction.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
-import repro.configs as configs
 import repro.core as core
-from repro.models import build_model
-from repro.models import sharding as shd
-from repro.runtime import ServeConfig, Server
-from repro.parallel.compat import set_mesh, shard_map
-
-
-def test_knn_lm_end_to_end(mesh8, rng):
-    """LM logits + Algorithm-2 retrieval -> valid mixed distribution."""
-    V = 8 * 32                       # sharded vocab
-    dm, N, l = 16, 8 * 256, 8
-    keys = rng.normal(size=(N, dm)).astype(np.float32)
-    values = rng.integers(0, V, size=(N,)).astype(np.int32)
-    h = rng.normal(size=(2, dm)).astype(np.float32)
-    lm_logits = rng.normal(size=(2, V)).astype(np.float32)
-
-    def step(kk, vv, hh, lml, key):
-        store = core.datastore.build_local(kk, vv, axis_name="x")
-        ret = core.datastore.retrieve(store, hh, l, key, axis_name="x")
-        mixed = core.datastore.interp_logits(lml, ret, 0.5, axis_name="x")
-        tok = core.topk_sample(mixed, 8, 0.7, jax.random.fold_in(key, 9),
-                               axis_name="x")
-        return mixed, tok
-
-    f = jax.jit(shard_map(
-        step, mesh=mesh8,
-        in_specs=(P("x"), P("x"), P(None), P(None, "x"), P(None)),
-        out_specs=(P(None, "x"), P(None)), check_vma=False))
-    mixed, tok = f(keys, values, h, lm_logits, jax.random.PRNGKey(0))
-    p = np.exp(np.asarray(mixed))
-    np.testing.assert_allclose(p.sum(-1), 1.0, rtol=1e-3)
-    assert tok.shape == (2,)
-    assert (np.asarray(tok) >= 0).all() and (np.asarray(tok) < V).all()
-
-
-def test_lm_generation_with_selection_sampler(mesh42, rng):
-    cfg = configs.get("qwen2-0.5b").reduced()
-    api = build_model(cfg)
-    with set_mesh(mesh42):
-        params = api.init_params(jax.random.PRNGKey(0))
-        specs = api.param_specs()
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(
-                x, NamedSharding(mesh42, shd.divisible(s, x.shape, mesh42))),
-            params, specs)
-        batch = {"tokens": rng.integers(0, cfg.vocab, (4, 8)).astype(
-            np.int32)}
-        srv = Server(api, params, ServeConfig(max_seq=32, top_k=16,
-                                              sampler="selection"),
-                     mesh=mesh42, cache_dtype=jnp.float32)
-        gen, stats = srv.generate(batch, 6, key=jax.random.PRNGKey(1))
-        srv2 = Server(api, params, ServeConfig(max_seq=32, top_k=16,
-                                               sampler="gather"),
-                      mesh=mesh42, cache_dtype=jnp.float32)
-        gen2, _ = srv2.generate(batch, 6, key=jax.random.PRNGKey(1))
-    assert gen.shape == (4, 6)
-    assert (gen >= 0).all() and (gen < cfg.vocab).all()
-    # paper sampler and gather baseline agree token-for-token (same key,
-    # same winner set)
-    np.testing.assert_array_equal(gen, gen2)
+from repro.parallel.compat import shard_map
 
 
 def test_knn_service_path(mesh8, rng):
-    """The quickstart/serve.py service: classification over clusters."""
+    """The quickstart service: classification over clusters."""
     from repro.data import gaussian_clusters
     n, dim, C, l = 8 * 512, 8, 4, 16
     pts, labels = gaussian_clusters(n, dim, C, seed=2)
