@@ -1,7 +1,7 @@
 """Per-query explain reports: "why was *this* query slow / broad /
 approximate?" answered from data the pipeline already computed.
 
-The serving path (runtime/knn_server.py ``_dispatch``) captures one
+The serving path (runtime/knn_server.py ``_finish``) captures one
 :class:`BatchCapture` per micro-batch — cheap references to the frozen
 objects the dispatch consumed (routing summaries, bucket index, the
 padded query block) plus the scalars it produced (touched-shard count,
@@ -58,7 +58,7 @@ def _digest(arr) -> str:
 class BatchCapture:
     """Dispatch-time facts shared by every request in one micro-batch.
 
-    Built once per ``_dispatch`` after the kernel returns; fields are
+    Built once per dispatch (``_finish``) after the readback; fields are
     references (frozen summaries/index, the dispatch's own padded query
     block) and scalars — no array copies, no recomputation.  ``timings``
     is filled in as the dispatch tail stamps its stages (reports are

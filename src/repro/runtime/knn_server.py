@@ -11,6 +11,11 @@ amortize every datastore pass over a query block.  Pipeline:
       (B, l_max) shape -> per-request QueryResult (dists / ids / values
       + round/message accounting from SelectionResult)
 
+The micro-batcher keeps one launch in flight: it takes and launches the
+next chunk while the device still runs the last one, and only then reads
+back and resolves the last (``_serve_loop``: ``_launch`` / ``_finish``);
+the synchronous ``flush`` runs the two halves back to back.
+
 Static shapes for jit: requests are padded to the smallest configured
 bucket size (padding rows carry l=0, which Algorithm 2 resolves to "select
 nothing" without touching real rows), and every per-request l shares the
@@ -132,6 +137,11 @@ from repro.store import index as index_mod
 from repro.store import summaries as summaries_mod
 
 _ID_SENTINEL = 2**31 - 1
+# How often the serving loop looks whether the device is done with the
+# batch in flight while it waits for the next chunk: the wait's timeout,
+# to which the kernel's timer slack (about 60 us) adds, so the loop sees
+# the device done within about 0.1 ms.
+_POLL_S = 4e-5
 
 # Same-thread spans of every tracer, the disabled one included, are also
 # annotations in a recording jax.profiler session (obs/trace.py), on the
@@ -284,6 +294,42 @@ class _Pending:
     span: object = None
 
 
+class _Batch:
+    """One micro-batch, from its chunk's take to its resolve.
+
+    ``_take_chunk_locked`` makes it under the queue lock: the chunk, its
+    place in the order batches finish in (``seq``), and whether another
+    batch of the server was in flight then (``overlapped``, what
+    ``serve.overlap`` observes).  ``_launch`` adds what the finish
+    needs: the padded block, the snapshot and routing it captured, the
+    stage stamps and spans, and the launched output with its
+    ``collect``; ``_finish`` reads them."""
+
+    def __init__(self, chunk: list, seq: int, overlapped: bool):
+        self.chunk = chunk
+        self.seq = seq
+        self.overlapped = overlapped
+        self.error: Optional[Exception] = None   # a failed launch's
+        self.spans: list = []      # every begun span, ended on error too
+        self.split = False         # launch spans ended with the call
+        self.out = None            # the launched output, on the device
+        self.touched = None        # touched-shard count
+        self.active = None         # (k,) batch-union shard keep
+        self.keep_arr = None       # (k, b) batch-union bucket keep
+        self.cand_frac = None      # search="approx" kept-live fraction
+        self.kl = None             # ensemble per-row local k
+        self.t_route0 = self.t_route1 = None    # host routing stamps
+
+    def ready(self) -> bool:
+        """Whether the device is done with the launch (a failed launch
+        has nothing on it); a failed program is ready too, and its
+        readback raises."""
+        if self.out is None:
+            return True
+        dev = self.out.buf if isinstance(self.out, _Packed) else self.out
+        return dev.is_ready()
+
+
 class KnnServer:
     """Serve l-NN queries against a mesh-sharded point set.
 
@@ -308,7 +354,8 @@ class KnnServer:
     Synchronous use: ``submit(...)`` then ``flush()`` (or ``query_batch``).
     Server use: ``with server.serving(): ...`` runs the micro-batcher
     thread, which lingers ``cfg.max_wait_ms`` after the first pending
-    request to fill a bucket before dispatching.
+    request to fill a bucket before dispatching, and launches the next
+    bucket while the device still runs the last one.
     """
 
     def __init__(self, points=None, values=None, labels=None, *, store=None,
@@ -500,6 +547,11 @@ class KnnServer:
 
         self._cv = threading.Condition()
         self._pending: list[_Pending] = []
+        # Batches taken from the queue and batches finished: a batch
+        # finishes in its turn, ``seq == _finished``, so futures resolve
+        # in the order their chunks were taken, whichever thread
+        # launched them; ``_taken > _finished`` means one is in flight.
+        self._taken = self._finished = 0
         self._thread: Optional[threading.Thread] = None
         self._running = False
         self.stats = ServerStats()
@@ -508,7 +560,7 @@ class KnnServer:
         # Tracer per cfg.obs_trace (no-op when off); a private metrics
         # registry (always live — counters/histograms are O(1) observes);
         # the Theorem-1 contract auditor (always on — it is arithmetic on
-        # numbers _dispatch computes anyway) and the sampled shadow-exact
+        # numbers a dispatch computes anyway) and the sampled shadow-exact
         # auditor (cfg.obs_audit_every > 0 and a pruned route).  A
         # store-backed server attaches its plane to the store so applies
         # and maintenance cycles land in the same trace/registry as the
@@ -523,7 +575,10 @@ class KnnServer:
         # chunk taken to resolve done, wait_s is the readback's block on
         # the device and copy_s the rest of the readback, cpu_s the
         # thread's CPU time over the same span; lock_wait_s is the
-        # snapshot stage's wait for the store lock.
+        # snapshot stage's wait for the store lock.  In the serving loop
+        # a batch's span also holds the wait for the next chunk and the
+        # next batch's launch, where one overlaps it; overlap is 1.0 for
+        # a batch launched while another of this server was in flight.
         self._m = {
             "queued": reg.histogram("serve.queued_s"),
             "prologue": reg.histogram("serve.prologue_s"),
@@ -532,6 +587,7 @@ class KnnServer:
             "kernel": reg.histogram("serve.kernel_s"),
             "wait": reg.histogram("serve.wait_s"),
             "copy": reg.histogram("serve.copy_s"),
+            "overlap": reg.histogram("serve.overlap"),
             "resolve": reg.histogram("serve.resolve_s"),
             "dispatch": reg.histogram("serve.dispatch_s"),
             "cpu": reg.histogram("serve.cpu_s"),
@@ -970,18 +1026,22 @@ class KnnServer:
         return [f.result() for f in futs]
 
     def flush(self):
-        """Drain the queue now, bucket by bucket (synchronous path)."""
+        """Drain the queue now, bucket by bucket (synchronous path): each
+        batch is launched and finished before the next is taken."""
         while True:
             with self._cv:
                 if not self._pending:
                     return
-                chunk = self._take_chunk_locked()
-            self._dispatch(chunk)
+                batch = self._take_chunk_locked()
+            self._finish(self._launch(batch))
 
-    def _take_chunk_locked(self) -> list[_Pending]:
+    def _take_chunk_locked(self) -> "_Batch":
         n = min(len(self._pending), self.cfg.bucket_sizes[-1])
         chunk, self._pending = self._pending[:n], self._pending[n:]
-        return chunk
+        batch = _Batch(chunk, seq=self._taken,
+                       overlapped=self._taken > self._finished)
+        self._taken += 1
+        return batch
 
     def _bucket_for(self, n: int) -> int:
         for b in self.cfg.bucket_sizes:
@@ -1021,33 +1081,22 @@ class KnnServer:
         return rounds, messages
 
     def _unpack_outputs(self, out):
-        """One executable's host outputs (``_run``) as ``(d, i, iters,
+        """One executable's host outputs (``_readback``) as ``(d, i, iters,
         surv, pred)`` where ``pred`` is the ``(label, confidence)`` pair
         when the config predicts and ``()`` otherwise (the executable's
         output arity follows the same flag)."""
         d, i, iters, surv = out[:4]
         return d, i, int(iters), surv, tuple(out[4:])
 
-    def _ensemble_call(self, kspan, operands, active, q, l_arr, touched):
-        """Serve one micro-batch in ensemble mode: local-k split on the
-        host, one collective-free launch (under ``kspan``, as ``_run``),
-        host aggregation.
-
-        Returns ``(d, i, iters, surv, pred, payload, votes, kl,
-        readback_s)`` shaped like the exact path's outputs so the
-        dispatch tail is shared: ``d``/``i`` are all-sentinel (no point
-        identity ever leaves its shard — that is the mode's bill),
-        ``payload`` the (k, B, C) per-shard answers for the explain vote
-        table, ``votes`` the (B, C) shard-vote tally (classification
-        only), ``kl`` the per-row local-k actually used, ``readback_s``
-        ``_run``'s pair.
-        """
+    def _ensemble_answers(self, payload, active, bucket: int):
+        """Host aggregation of one ensemble launch: ``payload`` is the
+        (k, B, C) per-shard answers, ``active`` the routed shards (None
+        = all).  Returns ``(d, i, iters, surv, pred, votes)`` shaped
+        like the exact path's outputs so the dispatch tail is shared:
+        ``d``/``i`` are all-sentinel (no point identity ever leaves its
+        shard — that is the mode's bill), ``votes`` the (B, C)
+        shard-vote tally (classification only)."""
         cfg = self.cfg
-        kl = predict_mod.local_k_for(l_arr, touched, cfg.local_k,
-                                     cfg.l_max)
-        ops = operands if active is None else operands + (active,)
-        payload, readback_s = self._run(kspan, self._ensemble_fn, *ops, q,
-                                        kl)
         act = (np.ones(self.k, bool) if active is None
                else np.asarray(active, bool))
         if cfg.predict == "vote":
@@ -1055,194 +1104,241 @@ class KnnServer:
         else:
             label, conf = predict_mod.aggregate_regress(payload, act)
             votes = None
-        b = q.shape[0]
-        d = np.full((b, cfg.l_max), np.inf, np.float32)
-        i = np.full((b, cfg.l_max), _ID_SENTINEL, np.int32)
-        surv = np.zeros(b, np.int32)
-        return d, i, 0, surv, (label, conf), payload, votes, kl, readback_s
+        d = np.full((bucket, cfg.l_max), np.inf, np.float32)
+        i = np.full((bucket, cfg.l_max), _ID_SENTINEL, np.int32)
+        surv = np.zeros(bucket, np.int32)
+        return d, i, 0, surv, (label, conf), votes
 
-    def _run(self, kspan, fn, *args):
-        """One launch of ``fn(*args)`` and its readback, as ``kspan``'s
-        leaves ``kernel.launch`` (the call returning, the copy home
-        started) and ``kernel.readback`` (``_readback``); returns
-        the outputs as host arrays and the readback's ``(wait_s,
-        copy_s)``: the seconds blocked on the device, then the seconds
-        to the host arrays."""
+    def _launch(self, b: "_Batch", split: bool = False) -> "_Batch":
+        """First half of a dispatch: the prologue, the snapshot, the
+        routing and the call, which returns with the copy home of its
+        output started (``_readback``).  From here the device runs the
+        batch on its own; ``_finish`` reads it back and resolves it.
+
+        A failed launch ends the batch's spans and leaves the error on
+        the batch for ``_finish`` to hand its futures, in their turn.
+        ``split`` (the serving loop) ends the batch's ``dispatch`` and
+        ``kernel`` spans as the call returns, so the spans the thread
+        opens next (the wait, the next batch's launch) never nest in
+        them; ``_finish`` opens siblings of the same names."""
         tracer = self.obs.tracer
-        with tracer.span("kernel.launch", parent=kspan):
-            collect = _readback(fn(*args))
-        with tracer.span("kernel.readback", parent=kspan):
-            out, wait_s, copy_s = collect()
-        return out, (wait_s, copy_s)
-
-    def _dispatch(self, chunk: list[_Pending]):
-        tracer = self.obs.tracer
-        t_take = time.perf_counter()
-        cpu0 = time.thread_time()
-        pspan = tracer.begin("dispatch.prologue")
-        n = len(chunk)
-        bucket = self._bucket_for(n)
-        q = np.zeros((bucket, self.dim), np.float32)
-        l_arr = np.zeros(bucket, np.int32)      # padding rows keep l=0
-        for row, rec in enumerate(chunk):
-            q[row] = rec.query
-            l_arr[row] = rec.l
-
-        # _dispatch may run concurrently from the micro-batcher thread and
-        # a caller's flush(); counter and stats updates go under the lock.
-        with self._cv:
-            batch_id = self._batch_counter
-            self._batch_counter += 1
-        key = jax.random.fold_in(self._base_key, batch_id)
-        pspan.end()
-        t_dispatch = time.perf_counter()
-        # Per-batch trace root; request trees point at it through their
-        # "serve" child's batch attribute (cross-tree reference by
-        # attribute, never by parent link — trees stay single-rooted).
-        dspan = tracer.begin("dispatch", t0=t_dispatch, batch=batch_id,
-                             bucket=bucket, n_real=n)
-        env = self._env_by_bucket[bucket]
-        batch_spans = [dspan]        # every begun span, ended on error too
-        # Stage boundaries are stamped explicitly (not read back off the
-        # spans) so the per-stage histograms stay populated with tracing
-        # off — the no-op span carries no clock.
+        b.t_take = time.perf_counter()
+        b.cpu0 = time.thread_time()
         try:
-            t_snap0 = time.perf_counter()
-            sspan = tracer.begin("snapshot", parent=dspan, t0=t_snap0)
-            batch_spans.append(sspan)
+            pspan = tracer.begin("dispatch.prologue")
+            b.spans.append(pspan)
+            n = len(b.chunk)
+            bucket = b.bucket = self._bucket_for(n)
+            q = np.zeros((bucket, self.dim), np.float32)
+            l_arr = np.zeros(bucket, np.int32)      # padding rows keep l=0
+            for row, rec in enumerate(b.chunk):
+                q[row] = rec.query
+                l_arr[row] = rec.l
+            b.q, b.l_arr = q, l_arr
+            # _launch may run concurrently from the micro-batcher thread
+            # and a caller's flush(); counter and stats updates go under
+            # the lock.
+            with self._cv:
+                b.batch_id = self._batch_counter
+                self._batch_counter += 1
+            key = b.key = jax.random.fold_in(self._base_key, b.batch_id)
+            pspan.end()
+            b.t_dispatch = time.perf_counter()
+            # Per-batch trace root; request trees point at it through
+            # their "serve" child's batch attribute (cross-tree reference
+            # by attribute, never by parent link — trees stay
+            # single-rooted).
+            dspan = b.dspan = tracer.begin(
+                "dispatch", t0=b.t_dispatch, batch=b.batch_id,
+                bucket=bucket, n_real=n)
+            b.spans.append(dspan)
+            env = self._env_by_bucket[bucket]
+            # Stage boundaries are stamped explicitly (not read back off
+            # the spans) so the per-stage histograms stay populated with
+            # tracing off — the no-op span carries no clock.
+            b.t_snap0 = time.perf_counter()
+            sspan = tracer.begin("snapshot", parent=dspan, t0=b.t_snap0)
+            b.spans.append(sspan)
             if self._store is not None:
                 waited0 = self._store.lock_wait_s()
             operands, generation, summ, idx = self._backing_arrays()
             if self._store is not None:
-                n_live = int(self._store.live_per_shard.sum())
-                maint0 = self._store.maint_commit_clock()
-                lock_wait = self._store.lock_wait_s() - waited0
+                b.n_live = int(self._store.live_per_shard.sum())
+                b.maint0 = self._store.maint_commit_clock()
+                b.lock_wait = self._store.lock_wait_s() - waited0
             else:
-                n_live = self.m_local * self.k
-                maint0 = (0, None)
-            sspan.end(generation=generation, n_live=n_live)
-            t_snap1 = time.perf_counter()
-            t_route0 = t_route1 = None
-            cand_frac = None       # search="approx" kept-live fraction
-            keep_arr = None        # (k, b) batch-union bucket keep
-            active_arr = None      # (k,) batch-union shard keep
-            pred = ()              # (label, conf) when predicting
-            epayload = evotes = kl = None    # ensemble-mode extras
-            kattrs = dict(path=env["path"], l2_path=env["l2_path"],
-                          fallback=env["fallback_reason"] or "")
+                b.n_live = self.m_local * self.k
+                b.maint0 = (0, None)
+            sspan.end(generation=generation, n_live=b.n_live)
+            b.t_snap1 = time.perf_counter()
+            b.operands, b.generation = operands, generation
+            b.summ, b.idx = summ, idx
+            b.kattrs = dict(path=env["path"], l2_path=env["l2_path"],
+                            fallback=env["fallback_reason"] or "")
             if self._route_fn is not None:
                 # Device routing: the Pallas prologue computes the
                 # touched-shard union inside the same launch as the
                 # query; ``active`` comes back with the batch — so the
                 # routing decision has no separate interval and its span
-                # is recorded over the fused launch.
-                t_kern0 = time.perf_counter()
-                kspan = tracer.begin("kernel", parent=dspan, t0=t_kern0,
-                                     route_compute="device", **kattrs)
-                batch_spans.append(kspan)
-                packed = self._packed_for(summ)
-                if self._indexed:
-                    iops = self._index_ops_for(idx)
-                    (*out, active_arr, keep_any), readback_s = self._run(
-                        kspan, self._route_fn, operands, packed, *iops, q,
-                        l_arr, key)
-                    keep_arr = keep_any.reshape(self.k, idx.num_buckets)
-                    cand_frac = index_mod.candidate_fraction(
-                        idx, keep_arr)
-                else:
-                    (*out, active_arr), readback_s = self._run(
-                        kspan, self._route_fn, operands, packed, q, l_arr,
-                        key)
-                d, i, iters, surv, pred = self._unpack_outputs(out)
-                touched = int(active_arr.sum())
-                kspan.end(touched=touched)
-                t_kern1 = time.perf_counter()
-                tracer.record("route", t_kern0, t_kern1, parent=dspan,
-                              compute="device", fused=True,
-                              touched=touched, slack=self.cfg.route_slack)
-            elif self.cfg.route == "pruned":
-                # Touched-shard set for this micro-batch: the union over
-                # real rows of the summary lower-bound survivors (padding
-                # rows carry l=0 and route nowhere).  One collective pass
-                # serves the whole batch, so the device mask is the union;
-                # accounting charges only the touched subset.
-                t_route0 = time.perf_counter()
-                rspan = tracer.begin("route", parent=dspan, t0=t_route0,
-                                     compute="host",
-                                     slack=self.cfg.route_slack)
-                batch_spans.append(rspan)
-                active_rows = summaries_mod.route_shards(
-                    summ, q, l_arr, slack=self.cfg.route_slack)
-                active = active_rows.any(axis=0)
-                active_arr = active
-                touched = int(active.sum())
-                extra = ()
-                if self._indexed:
-                    # Second prologue stage, bucket granularity: the
-                    # per-row shard keep gates which buckets can
-                    # compete, the batch-union bucket keep becomes the
-                    # (n,) per-slot candidate operand (store/index.py).
-                    pcand, cand_frac, keep_arr = self._host_candidates(
-                        idx, q, l_arr, active_rows)
-                    extra = (pcand,)
-                rspan.end(touched=touched)
-                t_route1 = time.perf_counter()
-                kspan = tracer.begin("kernel", parent=dspan, t0=t_route1,
-                                     route_compute="host", **kattrs)
-                batch_spans.append(kspan)
-                if self._ensemble:
-                    (d, i, iters, surv, pred, epayload, evotes, kl,
-                     readback_s) = self._ensemble_call(
-                         kspan, operands, active, q, l_arr, touched)
-                else:
-                    out, readback_s = self._run(
-                        kspan, self._fn, *operands, *extra, active, q,
-                        l_arr, key)
-                    d, i, iters, surv, pred = self._unpack_outputs(out)
-                kspan.end()
-                t_kern0, t_kern1 = t_route1, time.perf_counter()
+                # is recorded over the fused launch (``_finish``).
+                b.t_kern0 = time.perf_counter()
+                b.kattrs["route_compute"] = "device"
+                iops = self._index_ops_for(idx) if self._indexed else ()
+                fn, args = self._route_fn, (operands, self._packed_for(summ),
+                                            *iops, q, l_arr, key)
             else:
-                touched = self.k
-                extra = ()
-                if self._indexed:
-                    t_route0 = time.perf_counter()
+                shard_ops, extra = (), ()
+                b.touched = self.k
+                if self.cfg.route == "pruned":
+                    # Touched-shard set for this micro-batch: the union
+                    # over real rows of the summary lower-bound survivors
+                    # (padding rows carry l=0 and route nowhere).  One
+                    # collective pass serves the whole batch, so the
+                    # device mask is the union; accounting charges only
+                    # the touched subset.
+                    b.t_route0 = time.perf_counter()
                     rspan = tracer.begin("route", parent=dspan,
-                                         t0=t_route0, compute="host",
+                                         t0=b.t_route0, compute="host",
+                                         slack=self.cfg.route_slack)
+                    b.spans.append(rspan)
+                    active_rows = summaries_mod.route_shards(
+                        summ, q, l_arr, slack=self.cfg.route_slack)
+                    b.active = active_rows.any(axis=0)
+                    b.touched = int(b.active.sum())
+                    shard_ops = (b.active,)
+                    if self._indexed:
+                        # Second prologue stage, bucket granularity: the
+                        # per-row shard keep gates which buckets can
+                        # compete, the batch-union bucket keep becomes
+                        # the (n,) per-slot candidate operand
+                        # (store/index.py).
+                        pcand, b.cand_frac, b.keep_arr = \
+                            self._host_candidates(idx, q, l_arr,
+                                                  active_rows)
+                        extra = (pcand,)
+                    rspan.end(touched=b.touched)
+                    b.kattrs["route_compute"] = "host"
+                    b.t_route1 = time.perf_counter()
+                elif self._indexed:
+                    b.t_route0 = time.perf_counter()
+                    rspan = tracer.begin("route", parent=dspan,
+                                         t0=b.t_route0, compute="host",
                                          indexed=True)
-                    batch_spans.append(rspan)
-                    pcand, cand_frac, keep_arr = self._host_candidates(
+                    b.spans.append(rspan)
+                    pcand, b.cand_frac, b.keep_arr = self._host_candidates(
                         idx, q, l_arr, None)
                     extra = (pcand,)
                     rspan.end()
-                    t_route1 = time.perf_counter()
-                t_kern0 = time.perf_counter()
-                kspan = tracer.begin("kernel", parent=dspan, t0=t_kern0,
-                                     **kattrs)
-                batch_spans.append(kspan)
+                    b.t_route1 = time.perf_counter()
+                b.t_kern0 = time.perf_counter()
                 if self._ensemble:
-                    (d, i, iters, surv, pred, epayload, evotes, kl,
-                     readback_s) = self._ensemble_call(
-                         kspan, operands, None, q, l_arr, touched)
+                    # Ensemble mode: the local-k split on the host, one
+                    # collective-free launch, host aggregation after the
+                    # readback (``_ensemble_answers``).
+                    b.kl = predict_mod.local_k_for(
+                        l_arr, b.touched, self.cfg.local_k, self.cfg.l_max)
+                    fn, args = self._ensemble_fn, (*operands, *shard_ops, q,
+                                                   b.kl)
                 else:
-                    out, readback_s = self._run(
-                        kspan, self._fn, *operands, *extra, q, l_arr, key)
-                    d, i, iters, surv, pred = self._unpack_outputs(out)
-                kspan.end()
-                t_kern1 = time.perf_counter()
+                    fn, args = self._fn, (*operands, *extra, *shard_ops, q,
+                                          l_arr, key)
+            kspan = b.kspan = tracer.begin("kernel", parent=dspan,
+                                           t0=b.t_kern0, **b.kattrs)
+            b.spans.append(kspan)
+            # kernel.launch: the call returning, the copy home started;
+            # kernel.readback (``_finish``): the outputs in hand.
+            with tracer.span("kernel.launch", parent=kspan):
+                b.out = fn(*args)
+                b.collect = _readback(b.out)
         except Exception as exc:
-            # A failed dispatch must never strand its futures (the chunk
-            # already left the queue), kill the micro-batcher thread, or
-            # leave torn spans behind.
-            self._m["errors"].inc()
-            for rec in chunk:
+            self._fail(b, exc)
+            return b
+        b.t_launched = time.perf_counter()
+        if split:
+            kspan.end()
+            dspan.end()
+            b.split = True
+        return b
+
+    def _fail(self, b: "_Batch", exc: Exception) -> None:
+        """A failed dispatch: count it and end the batch's spans now, on
+        the thread that began them; the futures get the error in the
+        batch's turn (``_finish``)."""
+        self._m["errors"].inc()
+        b.error = exc
+        for sp in reversed(b.spans):      # Span.end is idempotent
+            sp.end(error=type(exc).__name__)
+
+    def _finish(self, b: "_Batch") -> None:
+        """Second half of a dispatch, in the batch's turn: batches finish
+        in the order their chunks left the queue, whichever thread took
+        them, so futures resolve in FIFO order."""
+        with self._cv:
+            while self._finished != b.seq:
+                self._cv.wait()
+        try:
+            if b.error is not None:
+                raise b.error
+            self._resolve_batch(b)
+        except Exception as exc:
+            # A failed launch, readback or shadow replay must never
+            # strand its futures (the chunk already left the queue), kill
+            # the micro-batcher thread, or leave torn spans behind.
+            if b.error is None:
+                self._fail(b, exc)
+            for rec in b.chunk:
                 _resolve(rec.future, error=exc)
                 if rec.span is not None:
                     rec.span.end(error=type(exc).__name__)
-            for sp in reversed(batch_spans):      # Span.end is idempotent
-                sp.end(error=type(exc).__name__)
-            return
+        finally:
+            with self._cv:
+                self._finished += 1
+                self._cv.notify_all()
+
+    def _resolve_batch(self, b: "_Batch") -> None:
+        """Read a launched batch back (``kernel.readback``), then the
+        accounting, the contract check, the capture, the shadow audit
+        and the per-request answers (``resolve``)."""
+        tracer = self.obs.tracer
+        dspan, kspan = b.dspan, b.kspan
+        if b.split:
+            dspan = tracer.begin("dispatch", batch=b.batch_id,
+                                 bucket=b.bucket, n_real=len(b.chunk))
+            kspan = tracer.begin("kernel", parent=dspan, **b.kattrs)
+            b.spans += [dspan, kspan]
+        with tracer.span("kernel.readback", parent=kspan):
+            host, wait_s, copy_s = b.collect()
+        epayload = evotes = None    # ensemble-mode extras
+        if self._route_fn is not None:
+            if self._indexed:
+                *out, b.active, keep_any = host
+                b.keep_arr = keep_any.reshape(self.k, b.idx.num_buckets)
+                b.cand_frac = index_mod.candidate_fraction(b.idx,
+                                                           b.keep_arr)
+            else:
+                *out, b.active = host
+            b.touched = int(b.active.sum())
+            d, i, iters, surv, pred = self._unpack_outputs(out)
+        elif self._ensemble:
+            epayload = host
+            d, i, iters, surv, pred, evotes = self._ensemble_answers(
+                host, b.active, b.bucket)
+        else:
+            d, i, iters, surv, pred = self._unpack_outputs(host)
+        kspan.end(touched=b.touched)
+        t_kern1 = time.perf_counter()
+        if self._route_fn is not None:
+            # Over the fused launch: to the readback's end, or to the
+            # call's return where the launch's spans ended with it.
+            tracer.record("route", b.t_kern0,
+                          b.t_launched if b.split else t_kern1,
+                          parent=b.dspan, compute="device", fused=True,
+                          touched=b.touched, slack=self.cfg.route_slack)
         t_done = time.perf_counter()
 
+        chunk, bucket, n = b.chunk, b.bucket, len(b.chunk)
+        batch_id, generation, touched = b.batch_id, b.generation, b.touched
+        q, l_arr, t_dispatch = b.q, b.l_arr, b.t_dispatch
         rounds, messages = self._accounting(iters, touched)
         self.stats.observe(
             bucket, n,
@@ -1254,9 +1350,9 @@ class KnnServer:
         audit_l = (self.cfg.l_max if self.cfg.sampler == "gather"
                    else l_real)
         contract_ok = self._contract.check(
-            l_max=audit_l, n_live=n_live, rounds=rounds, messages=messages,
-            use_sampling=self.cfg.use_sampling, sampler=self.cfg.sampler,
-            generation=generation)
+            l_max=audit_l, n_live=b.n_live, rounds=rounds,
+            messages=messages, use_sampling=self.cfg.use_sampling,
+            sampler=self.cfg.sampler, generation=generation)
         if self._store is not None:
             maint1 = self._store.maint_commit_clock()
             head_generation = self._store.generation
@@ -1278,20 +1374,20 @@ class KnnServer:
                            else "host"),
             search=self.cfg.search, slack=self.cfg.route_slack,
             oversample=self.cfg.index_oversample,
-            queries=q, ls=l_arr, summaries=summ, index=idx,
-            active=active_arr, keep_any=keep_arr, touched=touched,
-            candidate_fraction=cand_frac,
+            queries=q, ls=l_arr, summaries=b.summ, index=b.idx,
+            active=b.active, keep_any=b.keep_arr, touched=touched,
+            candidate_fraction=b.cand_frac,
             predict=self.cfg.predict, predict_mode=pmode,
             labels=(pred[0] if pred else None),
             confidences=(pred[1] if pred else None),
-            local_k=kl, shard_answers=epayload, votes=evotes,
+            local_k=b.kl, shard_answers=epayload, votes=evotes,
             timings={
-                "snapshot_s": t_snap1 - t_snap0,
-                "route_s": (t_route1 - t_route0
-                            if t_route0 is not None else None),
-                "kernel_s": t_kern1 - t_kern0,
+                "snapshot_s": b.t_snap1 - b.t_snap0,
+                "route_s": (b.t_route1 - b.t_route0
+                            if b.t_route0 is not None else None),
+                "kernel_s": t_kern1 - b.t_kern0,
             },
-            maint_before=maint0[0], maint_after=maint1[0],
+            maint_before=b.maint0[0], maint_after=maint1[0],
             maint_last=maint1[1], contract_ok=contract_ok)
         # Shadow-exact audit: replay every Nth pruned/indexed batch
         # through the same executable with every shard active and every
@@ -1316,7 +1412,7 @@ class KnnServer:
                     ok = self._shadow.check_labels(
                         pred[0], l_arr,
                         lambda: self._exact_label_replay(
-                            operands, all_on, q, l_arr, key),
+                            b.operands, all_on, q, l_arr, b.key),
                         generation=generation, batch_id=batch_id,
                         touched=touched)
                     if (self._slo is not None
@@ -1325,8 +1421,8 @@ class KnnServer:
                                           self._shadow.last_agreement)
                 else:
                     ok = self._shadow.check(
-                        d, i, lambda: self._exact_replay(operands, all_on,
-                                                         q, l_arr, key),
+                        d, i, lambda: self._exact_replay(
+                            b.operands, all_on, q, l_arr, b.key),
                         generation=generation, batch_id=batch_id,
                         touched=touched)
                     if (self._slo is not None
@@ -1338,6 +1434,7 @@ class KnnServer:
 
         t_res0 = time.perf_counter()
         vspan = tracer.begin("resolve", parent=dspan, t0=t_res0)
+        b.spans.append(vspan)
         for row, rec in enumerate(chunk):
             # ascending by distance (gather_selected packs by shard rank,
             # not by distance; l is small, so sort host-side — this also
@@ -1394,17 +1491,18 @@ class KnnServer:
         vspan.end()
         dspan.end(touched=touched, generation=generation)
         t_res1 = time.perf_counter()
-        cpu_s = time.thread_time() - cpu0
+        cpu_s = time.thread_time() - b.cpu0
         m = self._m
-        m["prologue"].observe(t_dispatch - t_take)
-        m["snapshot"].observe(t_snap1 - t_snap0)
+        m["prologue"].observe(t_dispatch - b.t_take)
+        m["snapshot"].observe(b.t_snap1 - b.t_snap0)
         if self._store is not None:
-            m["lock_wait"].observe(lock_wait)
-        m["kernel"].observe(t_kern1 - t_kern0)
-        m["wait"].observe(readback_s[0])
-        m["copy"].observe(readback_s[1])
-        if t_route0 is not None:
-            m["route"].observe(t_route1 - t_route0)
+            m["lock_wait"].observe(b.lock_wait)
+        m["kernel"].observe(t_kern1 - b.t_kern0)
+        m["wait"].observe(wait_s)
+        m["copy"].observe(copy_s)
+        m["overlap"].observe(1.0 if b.overlapped else 0.0)
+        if b.t_route0 is not None:
+            m["route"].observe(b.t_route1 - b.t_route0)
         m["resolve"].observe(t_res1 - t_res0)
         m["dispatch"].observe(t_res1 - t_dispatch)
         m["cpu"].observe(cpu_s)
@@ -1415,8 +1513,8 @@ class KnnServer:
         # observation — it must never enter the serving histograms.
         if touched >= 0:
             m["touched"].observe(touched)
-        if cand_frac is not None:
-            m["cand_frac"].observe(cand_frac)
+        if b.cand_frac is not None:
+            m["cand_frac"].observe(b.cand_frac)
         # Explain reports assemble only after the dispatch completes, so
         # this late fill is always visible to them.
         capture.timings["resolve_s"] = t_res1 - t_res0
@@ -1485,7 +1583,9 @@ class KnnServer:
           chunk under the lock before dispatching, so the final
           ``flush()`` can never re-dispatch a request the exiting
           batcher already took);
-        * FIFO order is preserved through the drain;
+        * FIFO order is preserved through the drain: the exiting batcher
+          finishes the batch it has in flight, and batches finish in the
+          order their chunks were taken, whichever thread took them;
         * stop() is idempotent and safe to race with itself — the
           thread handle is captured-and-cleared under the lock, so
           exactly one caller joins it and a second concurrent stop()
@@ -1503,28 +1603,50 @@ class KnnServer:
         return _Serving(self)
 
     def _serve_loop(self):
-        linger = self.cfg.max_wait_ms / 1e3
-        full = self.cfg.bucket_sizes[-1]
+        """Keep one launch in flight: once batch n is launched, take the
+        next chunk while the device still runs n, launch it (it queues
+        on the device behind n), and only then finish n.  When the
+        device is done with n before a chunk is ready, finish n first
+        and take work as before."""
         tracer = self.obs.tracer
+        inflight = None          # launched, not yet finished
         while True:
             wspan = tracer.begin("batcher.wait")
             with self._cv:
-                while self._running and not self._pending:
-                    self._cv.wait(timeout=0.1)
-                if not self._running:
-                    wspan.end()
-                    return
-                # Linger: give the batch a chance to fill before paying a
-                # datastore pass for a mostly-padded bucket.
-                deadline = self._pending[0].t_enqueue + linger
-                while (self._running and len(self._pending) < full
-                       and time.perf_counter() < deadline):
-                    self._cv.wait(timeout=max(
-                        deadline - time.perf_counter(), 1e-4))
-                chunk = self._take_chunk_locked()
-            wspan.end(n=len(chunk))
-            if chunk:
-                self._dispatch(chunk)
+                batch = self._next_chunk_locked(inflight)
+            wspan.end(n=0 if batch is None else len(batch.chunk))
+            if batch is not None:
+                self._launch(batch, split=True)
+            if inflight is not None:
+                self._finish(inflight)
+            elif batch is None:
+                return           # stopped, nothing in flight
+            inflight = batch
+
+    def _next_chunk_locked(self, inflight: Optional["_Batch"]):
+        """Wait for the next chunk under ``self._cv`` (a wait releases it
+        and the GIL, so callers can submit) and take it: a full bucket,
+        or what is pending once its head has lingered ``max_wait_ms``.
+        With ``inflight`` on the device, return None as soon as the
+        device is done with it, so a finished batch never waits on the
+        linger: polled every ``_POLL_S`` and at every submit.  None too
+        once the server stops."""
+        linger = self.cfg.max_wait_ms / 1e3
+        full = self.cfg.bucket_sizes[-1]
+        while self._running:
+            if self._pending:
+                left = self._pending[0].t_enqueue + linger \
+                    - time.perf_counter()
+                if len(self._pending) >= full or left <= 0:
+                    return self._take_chunk_locked()
+            if inflight is None:
+                timeout = max(left, 1e-4) if self._pending else 0.1
+            elif inflight.ready():
+                return None
+            else:
+                timeout = _POLL_S
+            self._cv.wait(timeout=timeout)
+        return None
 
 
 def _distances_fn(cfg: KnnServiceConfig):

@@ -5,6 +5,7 @@ thread-safety under concurrent observe/snapshot."""
 
 import math
 import threading
+import time
 
 import jax
 import numpy as np
@@ -401,7 +402,7 @@ def test_server_stats_rejects_touched_sentinel():
 def test_touched_sentinel_never_served_or_observed(mesh8, pts, route):
     """Both routes end to end: every served result carries a
     non-negative shards_touched (the -1 default never escapes
-    _dispatch), the prune math saw no invalid observations, and the
+    the dispatch), the prune math saw no invalid observations, and the
     serve.touched_shards histogram observed only real counts — exactly
     one per dispatched batch, k under route="exact"."""
     srv = _server(pts, mesh8, route=route)
@@ -472,3 +473,227 @@ def test_readback_matches_plain_reads(mesh8, pts, monkeypatch, form):
         assert a.generation == b.generation
     if "predict" in knobs:
         assert all(r.label is not None for r in overlapped)
+
+
+# ---- one launch in flight -----------------------------------------------
+
+def _pending_buckets(srv, qs, ls):
+    """Submit every query before the micro-batcher starts, so the loop
+    takes its chunks in the order flush() would: full buckets first."""
+    return [srv.submit(q, l) for q, l in zip(qs, ls)]
+
+
+def test_second_full_bucket_launches_before_first_is_read_back(mesh8, pts):
+    """Two full buckets pending and a slow device (the kernels run in
+    interpret mode): the loop launches the second while the first still
+    runs, reads the first back only after, and ``serve.overlap`` counts
+    one overlapped launch of two.  The ring stays well formed: each
+    batch's launch and finish are sibling ``dispatch`` trees."""
+    from repro.obs.trace import build_trees
+    srv = _server(pts, mesh8, bucket_sizes=(4,), l_max=16, obs_trace=True)
+    srv.warmup()
+    qs = np.random.default_rng(11).normal(size=(8, DIM)).astype(np.float32)
+    futs = _pending_buckets(srv, qs, [8] * 8)
+    with srv.serving():
+        res = [f.result(timeout=120) for f in futs]
+    for r, q in zip(res, qs):
+        bd, _ = _brute(pts, q[None], 8)
+        np.testing.assert_allclose(r.dists, bd[0], rtol=1e-4)
+    overlap = srv.obs_snapshot()["metrics"]["serve.overlap"]
+    assert (overlap["count"], overlap["sum"]) == (2, 1.0)
+    recs = srv.obs.tracer.spans()
+    build_trees(recs)
+    assert srv.obs.tracer.active_count() == 0
+    by_id = {r["span"]: r for r in recs}
+
+    def batch(r):
+        while r["name"] != "dispatch":
+            r = by_id[r["parent"]]
+        return r["attrs"]["batch"]
+
+    launch = {batch(r): r for r in recs if r["name"] == "kernel.launch"}
+    readback = {batch(r): r for r in recs if r["name"] == "kernel.readback"}
+    assert launch[1]["t0"] < readback[0]["t0"]
+    assert readback[0]["t1"] <= readback[1]["t0"]
+    dispatches = [r for r in recs if r["name"] == "dispatch"]
+    assert sorted(d["attrs"]["batch"] for d in dispatches) == [0, 0, 1, 1]
+    srv.close()
+
+
+def _matrix_server(form, pts, mesh8):
+    knobs = dict(dim=DIM, l_max=16, bucket_sizes=(4,), obs_trace=True)
+    if form.startswith("store"):
+        from repro.store import MutableStore
+        cfg = CONFIG.replace(**knobs, store_capacity_per_shard=64,
+                             sampler="gather" if form == "store_gather"
+                             else "selection")
+        store = MutableStore(DIM, mesh=mesh8, axis_name="x",
+                             **cfg.store_kwargs())
+        ids = store.insert(pts[:400])
+        store.flush()
+        store.delete(ids[::7])
+        store.flush()
+        return KnnServer(store=store, cfg=cfg), pts[:400]
+    extra = {"static": {}, "gather": dict(sampler="gather"),
+             "pruned_host": dict(route="pruned", route_compute="host"),
+             "device_route": dict(route="pruned", route_compute="device"),
+             "exact_predict": dict(predict="vote", num_classes=4)}[form]
+    labels = (np.random.default_rng(5).integers(0, 4, N).astype(np.float32)
+              if "predict" in extra else None)
+    return KnnServer(pts, labels=labels, mesh=mesh8, axis_name="x",
+                     cfg=CONFIG.replace(**knobs, **extra)), pts
+
+
+@pytest.mark.parametrize("form", ["static", "gather", "store",
+                                  "store_gather", "pruned_host",
+                                  "device_route", "exact_predict"])
+def test_overlapped_answers_match_a_loop_that_never_overlaps(mesh8, pts,
+                                                              form):
+    """The serving loop with a launch in flight answers bit for bit as
+    flush(), which never overlaps: the same chunks, batch keys and
+    generation, for each backing, sampler, route and exact predict."""
+    from repro.obs.trace import build_trees
+    srv, _ = _matrix_server(form, pts, mesh8)
+    qs = np.random.default_rng(12).normal(size=(11, DIM)).astype(np.float32)
+    ls = [1, 5, 16, 8, 3, 16, 2, 9, 16, 4, 7]
+    futs = _pending_buckets(srv, qs, ls)
+    with srv.serving():
+        served = [f.result(timeout=120) for f in futs]
+    assert srv.obs.metrics.histogram("serve.overlap").total >= 1.0
+    build_trees(srv.obs.tracer.spans())
+    srv._batch_counter = 0
+    flushed = srv.query_batch(qs, ls)
+    srv.close()
+    assert [r.bucket for r in served] == [r.bucket for r in flushed]
+    for a, b in zip(served, flushed):
+        assert a.dists.tobytes() == b.dists.tobytes()
+        assert a.ids.tobytes() == b.ids.tobytes()
+        assert (a.survivors, a.iterations) == (b.survivors, b.iterations)
+        assert (a.label, a.confidence) == (b.label, b.confidence)
+        assert (a.generation, a.shards_touched) == (b.generation,
+                                                    b.shards_touched)
+    if form == "exact_predict":
+        assert all(r.label is not None for r in served)
+
+
+def test_stop_and_concurrent_flush_resolve_fifo_exactly_once(mesh8, pts,
+                                                          monkeypatch):
+    """stop() and a caller's flush() racing the loop while it has a batch
+    in flight (its readback held back 50 ms): every future resolves
+    exactly once (``stats.queries`` is the double-dispatch detector) and
+    futures resolve in the order they were submitted, whichever thread
+    launched their batch."""
+    from repro.runtime import knn_server as ks
+    real = ks._readback
+
+    def slow_in_the_loop(out):
+        collect = real(out)
+
+        def later():
+            if threading.current_thread().name == "knn-microbatcher":
+                time.sleep(0.05)
+            return collect()
+        return later
+
+    srv = _server(pts, mesh8, bucket_sizes=(4,), l_max=16, max_wait_ms=1.0)
+    srv.warmup()
+    monkeypatch.setattr(ks, "_readback", slow_in_the_loop)
+    rng = np.random.default_rng(13)
+    futs, order = [], []
+
+    def submit(n):
+        for q in rng.normal(size=(n, DIM)).astype(np.float32):
+            f = srv.submit(q, 4)
+            f.add_done_callback(lambda _, k=len(futs): order.append(k))
+            futs.append(f)
+
+    submit(8)
+    srv.start()
+    while srv._taken < 1:          # the loop has its first batch
+        time.sleep(1e-4)
+    submit(18)
+    flusher = threading.Thread(target=srv.flush)
+    flusher.start()
+    submit(5)
+    srv.stop()
+    flusher.join()
+    assert all(f.done() and f.exception() is None for f in futs)
+    assert srv.stats.queries == len(futs)
+    assert order == list(range(len(futs)))
+    assert srv._taken == srv._finished
+    srv.close()
+
+
+def test_failed_next_launch_fails_only_its_own_futures(mesh8, pts):
+    """The launch of batch n+1 raises while n is in flight: n+1's
+    futures get the error, n still resolves, first, and the loop keeps
+    serving."""
+    srv = _server(pts, mesh8, bucket_sizes=(4,), l_max=16, obs_trace=True)
+    srv.warmup()
+    launches = []
+    real = srv._fn
+
+    def second_fails(*args):
+        launches.append(len(launches))
+        if len(launches) == 2:
+            raise RuntimeError("launch failed")
+        return real(*args)
+
+    srv._fn = second_fails
+    qs = np.random.default_rng(14).normal(size=(8, DIM)).astype(np.float32)
+    futs = _pending_buckets(srv, qs, [8] * 8)
+    order = []
+    for k, f in enumerate(futs):
+        f.add_done_callback(lambda _, k=k: order.append(k))
+    with srv.serving():
+        for f in futs[:4]:
+            r = f.result(timeout=120)
+            assert r.ids.shape == (8,)
+        for f in futs[4:]:
+            with pytest.raises(RuntimeError, match="launch failed"):
+                f.result(timeout=120)
+        again = srv.submit(qs[0], 8).result(timeout=120)
+    assert order == list(range(8))
+    assert again.ids.tobytes() == futs[0].result().ids.tobytes()
+    assert srv.obs.metrics.value("serve.dispatch_errors") == 1
+    # the failed launch was the overlapped one: only dispatches that
+    # answer are observed
+    overlap = srv.obs.metrics.histogram("serve.overlap")
+    assert (overlap.count, overlap.total) == (2, 0.0)
+    assert srv.obs.tracer.active_count() == 0
+    srv.close()
+
+
+def test_failed_finish_fails_its_own_futures_and_the_loop_serves_on(mesh8,
+                                                                    pts):
+    """A failure after the readback (here the shadow audit's replay, a
+    launch of its own) hands that batch's futures the error; the batch
+    launched over it still answers, its turn still comes, and stop()
+    returns."""
+    srv = _server(pts, mesh8, bucket_sizes=(4,), l_max=16, route="pruned",
+                  obs_audit_every=1, obs_trace=True)
+    srv.warmup()
+    replay = srv._exact_replay
+    calls = []
+
+    def first_fails(*args):
+        calls.append(None)
+        if len(calls) == 1:
+            raise RuntimeError("replay failed")
+        return replay(*args)
+
+    srv._exact_replay = first_fails
+    qs = np.random.default_rng(15).normal(size=(8, DIM)).astype(np.float32)
+    futs = _pending_buckets(srv, qs, [8] * 8)
+    with srv.serving():
+        for f in futs[:4]:
+            with pytest.raises(RuntimeError, match="replay failed"):
+                f.result(timeout=120)
+        for f, q in zip(futs[4:], qs[4:]):
+            bd, _ = _brute(pts, q[None], 8)
+            np.testing.assert_allclose(f.result(timeout=120).dists, bd[0],
+                                       rtol=1e-4)
+    assert srv.obs.metrics.value("serve.dispatch_errors") == 1
+    assert srv._taken == srv._finished == 2
+    assert srv.obs.tracer.active_count() == 0
+    srv.close()
