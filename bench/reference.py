@@ -7,6 +7,16 @@ and takes nothing the program made: the points are the benchmark's own,
 ids are the ones the benchmark assigned, and which ids are live at which
 generation comes from the benchmark's log of its write batches.
 
+Its distance is ``f64_distance``: |q|^2 - 2 q.p + |p|^2 in float64, each
+term a plain numpy row sum, so that a distance depends on its two points
+alone (a BLAS product's last bit depends on where its block and thread
+split put the column).  The brute force screens every base point in
+float32 blocks on a thread pool, keeping each point whose screened
+distance lies within twice its float32 rounding bound of the query's
+threshold, and computes only the few that can be nearest in float64: the
+answer is the one a full float64 sort over every live point gives, bit
+for bit, whatever the block size or the number of threads.
+
 The comparison follows ``chip_smoke.py`` (float64 brute force, float32
 rounding-bound tie rule), copied here so the yardstick cannot move with
 the program.  For each checked answer:
@@ -30,12 +40,20 @@ explicitly so it means the same on every backend.
 
 from __future__ import annotations
 
+import os
+from concurrent.futures import ThreadPoolExecutor
+
 import numpy as np
+from threadpoolctl import threadpool_limits
 
 U32 = 2.0 ** -24
-CHUNK = 1 << 17          # base points per scan block
+CHUNK = 1 << 13          # base points per scan block
+SAMPLE = 1 << 17         # leading base points that set each threshold
 MARGIN = 64              # candidates kept beyond l per query
 NEVER = np.iinfo(np.int64).max
+WORKERS = os.cpu_count() or 1
+POOL_BYTES = 4 << 30     # blocks in flight, all workers together
+BLOCK_BYTES = 16         # per query and point of a block in flight
 
 
 def gamma(dim: int) -> float:
@@ -44,12 +62,13 @@ def gamma(dim: int) -> float:
     return n * U32 / (1 - n * U32)
 
 
-def f64_distances(q: np.ndarray, p: np.ndarray) -> np.ndarray:
-    """(nq, m) squared L2 distances in float64."""
-    q = q.astype(np.float64)
-    p = p.astype(np.float64)
-    return ((q * q).sum(1)[:, None] - 2.0 * (q @ p.T)
-            + (p * p).sum(1)[None, :])
+def f64_distance(q: np.ndarray, p: np.ndarray) -> np.ndarray:
+    """(m,) squared L2 distances of one query ``q`` (dim,) to ``p``
+    (m, dim) in float64, ``|q|^2 - 2 q.p + |p|^2`` with each term a row
+    sum of float64 products."""
+    q = np.asarray(q, np.float64)[None, :]
+    p = np.asarray(p, np.float64)
+    return (q * q).sum(1) - 2.0 * (p * q).sum(1) + (p * p).sum(1)
 
 
 _HIGH3 = None
@@ -153,58 +172,152 @@ class LiveSet:
         return ok & (gen_in <= gen) & (gen < self._gen_out[safe])
 
 
-def _base_candidates(live: LiveSet, queries: np.ndarray, keep: int, dist):
-    """(nq, keep) ids and distances of the nearest base points, by
-    (distance, id).
+class _Float32Screen:
+    """The float64 reference's scan.  A block is screened in float32 as
+    ``|p|^2 - 2 q.p`` (``|q|^2``, the same along a row, is added after),
+    with a margin of twice the float32 rounding bound of the expansion,
+    ``gamma * (|q| + |p|)^2``, at the block's largest ``|p|``; the points
+    that can be nearest are computed again by ``f64_distance``."""
 
-    The keep-th smallest distance within the first block bounds each
-    query's keep-th smallest over all points from above, so only the
-    points at or below it are kept from each block."""
-    nq = len(queries)
-    keep = min(keep, live.n_base)
-    d0 = dist(queries, live.base[:max(CHUNK, keep)])
-    tau = np.partition(d0, keep - 1, axis=1)[:, keep - 1]
-    rows, ids, ds = [], [], []
-    for s in range(0, live.n_base, CHUNK):
-        d = dist(queries, live.base[s:s + CHUNK])
-        r, c = np.nonzero(d <= tau[:, None])
-        rows.append(r)
-        ids.append(s + c)
-        ds.append(d[r, c])
-    rows, ids, ds = (np.concatenate(x) for x in (rows, ids, ds))
-    order = np.lexsort((ids, ds, rows))
-    rows, ids, ds = rows[order], ids[order], ds[order]
-    first = np.searchsorted(rows, np.arange(nq))
-    take = first[:, None] + np.arange(keep)[None, :]
-    return ids[take], ds[take]
+    def __init__(self, queries: np.ndarray):
+        self.q = queries
+        q64 = queries.astype(np.float64)
+        self.qn = (q64 * q64).sum(1)
+        self.qabs = np.sqrt(self.qn)
+        self.two_gamma = 2.0 * gamma(queries.shape[1])
+
+    def block(self, rows: np.ndarray, p: np.ndarray) -> tuple:
+        """Screened distances of queries ``rows`` to ``p`` less their
+        ``offset`` (len(rows), m); each row's margin and offset."""
+        pn = (p * p).sum(1)
+        s = (-2.0 * self.q[rows]) @ p.T
+        s += pn
+        pmax = np.sqrt(float(pn.max()) * (1.0 + self.two_gamma))
+        return (s, self.two_gamma * (self.qabs[rows] + pmax) ** 2,
+                self.qn[rows])
+
+    def exact(self, j: int, p: np.ndarray) -> np.ndarray:
+        return f64_distance(self.q[j], p)
 
 
-def search(live: LiveSet, queries: np.ndarray, gens, ls, dist=f64_distances):
+class _Plain:
+    """Distances by ``dist`` as they are: no margin, none computed again."""
+
+    exact = None
+
+    def __init__(self, queries: np.ndarray, dist):
+        self.q = queries
+        self.dist = dist
+
+    def block(self, rows: np.ndarray, p: np.ndarray) -> tuple:
+        zero = np.zeros(len(rows))
+        return np.asarray(self.dist(self.q[rows], p), np.float64), zero, zero
+
+
+def _map(fn, starts: range, nq: int) -> list:
+    """``fn`` over the blocks at ``starts``, results in block order, on as
+    many threads as keep the blocks in flight within ``POOL_BYTES``."""
+    workers = min(WORKERS, len(starts),
+                  POOL_BYTES // (BLOCK_BYTES * max(nq, 1) * CHUNK))
+    if workers <= 1:
+        return [fn(s) for s in starts]
+    # one BLAS thread per block: the threads here are the parallelism
+    with threadpool_limits(1, user_api="blas"), \
+            ThreadPoolExecutor(workers) as pool:
+        return list(pool.map(fn, starts))
+
+
+def _at_least(x: np.ndarray, dtype) -> np.ndarray:
+    """The least values of ``dtype`` not below ``x``."""
+    y = x.astype(dtype)
+    return np.where(y < x, np.nextafter(y, np.array(np.inf, dtype)), y)
+
+
+def _bound(live: LiveSet, metric, keep: int) -> np.ndarray:
+    """Per query, an upper bound on the keep-th smallest distance over
+    the base: the keep-th smallest screened one over the leading
+    ``SAMPLE`` points, plus the margin."""
+    nq = len(metric.q)
+    if live.n_base <= keep:
+        return np.full(nq, np.inf)
+    n = min(live.n_base, max(SAMPLE, keep))
+    every = np.arange(nq)
+
+    def one(start):
+        s, e, off = metric.block(every, live.base[start:min(start + CHUNK,
+                                                                n)])
+        k = min(keep, s.shape[1])
+        return np.partition(s, k - 1, axis=1)[:, :k] + off[:, None], e
+
+    parts = _map(one, range(0, n, CHUNK), nq)
+    low = np.concatenate([p[0] for p in parts], axis=1)
+    return (np.partition(low, keep - 1, axis=1)[:, keep - 1]
+            + np.max([p[1] for p in parts], axis=0))
+
+
+def _scan(live: LiveSet, metric, rows: np.ndarray, bound: np.ndarray):
+    """Every base point that can lie at or below ``bound`` from a query of
+    ``rows``: (first, ids, screened, margin), grouped by query in the
+    order of ``rows``, ascending by id within; query i's run starts at
+    ``first[i]``."""
+
+    def one(start):
+        s, e, off = metric.block(rows, live.base[start:start + CHUNK])
+        r, c = np.nonzero(s <= _at_least(bound + e - off, s.dtype)[:, None])
+        return r, start + c, s[r, c] + off[r], e[r]
+
+    parts = _map(one, range(0, live.n_base, CHUNK), len(rows))
+    r, ids, s, e = (np.concatenate(x) for x in zip(*parts))
+    order = np.argsort(r, kind="stable")
+    first = np.searchsorted(r[order], np.arange(len(rows) + 1))
+    return first, ids[order], s[order], e[order]
+
+
+def search(live: LiveSet, queries, gens, ls, dist=None):
     """Exact l-NN of each query over the live set at its generation, by
-    ``dist``; returns one ``(ids, dists)`` pair per query, ascending by
+    ``f64_distance``, or by ``dist`` ((nq, dim), (m, dim) -> (nq, m), the
+    control's); returns one ``(ids, dists)`` pair per query, ascending by
     (distance, id)."""
-    queries = np.asarray(queries, np.float32)
+    queries = np.asarray(queries, np.float32).reshape(
+        -1, live.base.shape[1])
+    if not len(queries):
+        return []
+    metric = _Float32Screen(queries) if dist is None else _Plain(queries,
+                                                                 dist)
     keep = int(max(ls)) + MARGIN
-    cand_ids, cand_d = _base_candidates(live, queries, keep, dist)
+    every = np.arange(len(queries))
+    bound = _bound(live, metric, keep)
+    first, cand_ids, cand_s, cand_e = _scan(live, metric, every, bound)
     n_ins = len(live.inserted)
     ins_ids = np.arange(live.n_base, live.n_base + n_ins)
-    ins_d = (dist(queries, live.inserted) if n_ins
-             else np.empty((len(queries), 0)))
+    if n_ins:
+        ins_s, ins_e, off = metric.block(every, live.inserted)
+        ins_s = ins_s + off[:, None]
     out = []
     for j, (g, l) in enumerate(zip(gens, ls)):
-        b_ok = live.alive(cand_ids[j], g)
-        if b_ok.sum() < l and keep < live.n_base:
+        run = slice(first[j], first[j + 1])
+        ids, s, e = cand_ids[run], cand_s[run], cand_e[run]
+        ok = live.alive(ids, g)
+        if (np.isfinite(bound[j])
+                and np.count_nonzero(ok & (s + e <= bound[j])) < l):
             # more deletions among the candidates than the margin covers:
-            # scan every base point for this query
-            d_all = dist(queries[j:j + 1], live.base)[0]
-            all_ids = np.arange(live.n_base)
-            ok = live.alive(all_ids, g)
-            b_ids, b_d = all_ids[ok], d_all[ok]
-        else:
-            b_ids, b_d = cand_ids[j][b_ok], cand_d[j][b_ok]
-        i_ok = live.alive(ins_ids, g) if n_ins else np.zeros(0, bool)
-        ids = np.concatenate([b_ids, ins_ids[i_ok]])
-        d = np.concatenate([b_d, ins_d[j][i_ok]])
+            # every base point for this query
+            _, ids, s, e = _scan(live, metric, every[j:j + 1],
+                                 np.array([np.inf]))
+            ok = live.alive(ids, g)
+        ids, s, e = ids[ok], s[ok], e[ok]
+        if n_ins:
+            i_ok = live.alive(ins_ids, g)
+            ids = np.concatenate([ids, ins_ids[i_ok]])
+            s = np.concatenate([s, ins_s[j][i_ok]])
+            e = np.concatenate([e, np.full(np.count_nonzero(i_ok),
+                                           ins_e[j])])
+        if len(ids) > l:
+            # the l nearest lie within their margins of the l-th bound
+            near = s - e <= np.partition(s + e, l - 1)[l - 1]
+            ids, s = ids[near], s[near]
+        d = s if metric.exact is None else metric.exact(
+            j, live.points_of(ids))
         order = np.lexsort((ids, d))[:l]
         out.append((ids[order], d[order]))
     return out

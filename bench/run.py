@@ -269,9 +269,9 @@ def sample_answers(load: Load, win, seed: int, sample: int) -> list:
              r.result.dists) for r in (answered[i] for i in np.sort(pick))]
 
 
-def truth(system: System, answers: list, dist=reference.f64_distances):
+def truth(system: System, answers: list, dist=None):
     """The reference's answers to the same queries at the same
-    generations (``dist`` in float64, or the control's)."""
+    generations: in float64, or by ``dist`` (the control's)."""
     return reference.search(system.live, [a[0] for a in answers],
                             [a[2] for a in answers],
                             [a[1] for a in answers], dist)
